@@ -40,6 +40,7 @@ from repro.core import QuantConfig, QuantPolicy, dequantize_tree, \
 from repro.data.classification import ClsDataset, batches, \
     emotion_like  # noqa: E402
 from repro.engine import Engine, EngineConfig  # noqa: E402
+from repro.launch.compile_cache import use_compile_cache  # noqa: E402
 from repro.models import bert_tiny, get_model  # noqa: E402
 
 from run import provenance  # noqa: E402
@@ -246,6 +247,7 @@ def main():
     ap.add_argument("--out", default=os.path.join(
         os.path.dirname(__file__), "..", "BENCH_calib.json"))
     args = ap.parse_args()
+    use_compile_cache()
 
     # smoke keeps the full pipeline but shrinks training enough for CI;
     # below ~4 epochs the model is too untrained for sensitivity to rank

@@ -60,4 +60,6 @@ if __name__ == "__main__":
     import os
     import sys
     sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
+    from repro.launch.compile_cache import use_compile_cache
+    use_compile_cache()
     run()

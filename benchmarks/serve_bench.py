@@ -49,6 +49,7 @@ from run import provenance  # noqa: E402
 from repro.configs import get_arch  # noqa: E402
 from repro.engine import (Engine, EngineConfig,  # noqa: E402
                           admission_set_point)
+from repro.launch.compile_cache import use_compile_cache  # noqa: E402
 from repro.models import get_model  # noqa: E402
 from repro.obs import token_agreement  # noqa: E402
 from repro.runtime.serve_loop import Request, ServeConfig, Server  # noqa: E402
@@ -320,6 +321,7 @@ def main():
     ap.add_argument("--out", default=os.path.join(
         os.path.dirname(__file__), "..", "BENCH_serve.json"))
     args = ap.parse_args()
+    use_compile_cache()
     if args.smoke:
         args.requests = min(args.requests, 8)
         args.repeats = 1

@@ -43,6 +43,7 @@ from repro.calib import QuantRecipe  # noqa: E402
 from repro.configs import get_arch  # noqa: E402
 from repro.core import QuantConfig, QuantPolicy, quantize_tree  # noqa: E402
 from repro.engine import Engine, EngineConfig  # noqa: E402
+from repro.launch.compile_cache import use_compile_cache  # noqa: E402
 from repro.models import get_model  # noqa: E402
 from repro.obs import token_agreement  # noqa: E402
 
@@ -111,6 +112,7 @@ def main():
     ap.add_argument("--out", default=os.path.join(
         os.path.dirname(__file__), "..", "BENCH_spec.json"))
     args = ap.parse_args()
+    use_compile_cache()
     requests = args.requests or (6 if args.smoke else 16)
     new_tokens = args.new_tokens or (24 if args.smoke else 48)
     repeats = args.repeats or (1 if args.smoke else 3)

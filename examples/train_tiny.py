@@ -50,7 +50,7 @@ def main():
     opt_cfg = adamw.OptConfig(lr=1e-3, total_steps=args.steps,
                               warmup_steps=20)
 
-    with mesh, tempfile.TemporaryDirectory() as ckpt_dir:
+    with jax.set_mesh(mesh), tempfile.TemporaryDirectory() as ckpt_dir:
         params = model.init(key, cfg)
         n = sum(x.size for x in jax.tree.leaves(params))
         print(f"model: {n/1e6:.1f}M params, mesh {dict(mesh.shape)}")

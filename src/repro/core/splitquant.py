@@ -74,7 +74,9 @@ class SplitQuantTensor:
         return self.scale.ndim - self.stack_dims == 2
 
     def _select(self, vals: jnp.ndarray) -> jnp.ndarray:
-        """vals: (*stack, k[, out]) → per-element (*stack, *orig_shape)."""
+        """vals: (*stack, k[, out]) → per-element (*stack, *orig_shape).
+        A masked sum over the k clusters: elementwise, where a gather of
+        one value per element runs orders of magnitude slower on TPU."""
         b = self.stack_dims
         m = len(self.orig_shape)
         stack = vals.shape[:b]
@@ -83,8 +85,9 @@ class SplitQuantTensor:
             v = v.reshape(stack + (1,) * (m - 1) + v.shape[-2:])
         else:
             v = vals.reshape(stack + (1,) * m + (self.k,))
-        idx = self.cid[..., None].astype(jnp.int32)
-        return jnp.take_along_axis(v, idx, axis=-1)[..., 0]
+        cid = self.cid.astype(jnp.int32)
+        return sum(jnp.where(cid == c, v[..., c], 0.0)
+                   for c in range(self.k))
 
     def dequantize(self) -> jnp.ndarray:
         s = self._select(self.scale)

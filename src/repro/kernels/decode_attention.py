@@ -19,19 +19,24 @@ Shapes (one layer, decode S=1 per slot):
 
 Grid: (N slots, T / Tc chunks) — chunk index fastest, so the (m, l, acc)
 online-softmax state for one slot lives in VMEM scratch across its chunk
-sweep and the output block is written once at the final chunk. Blocks per
-program: q (1, Hq, D), K/V (1, Tc, Hkv, D), scales (1, Tc, Hkv, C) dynamic
-/ (1, 1, Hkv, C) static, kv_pos (1, Tc); q_pos rides in SMEM. GQA (Hq =
-G·Hkv) is accumulated in the grouped (Hkv, G, ·) layout — K/V are never
-broadcast to Hq. Chunks whose kv_pos entries are all -1 (dead slots,
-unwritten tail) are skipped under `pl.when`: past the validity mask they
-cost no flops, so a 512-deep cache with 100-deep occupants does ~1/4 of
-the work. Fully-empty slots return exact 0 (the materialized reference
-returns a meaningless mean-V row there; the engine discards both).
+sweep and the output block is written once at the final chunk. The kernel
+sees the cache time-minor (`to_kernel_layout`: (N, Hkv, D, T)), so a block
+never pads a D or C below 128 out to a lane tile. Blocks per program: q
+(1, Hkv, G, D), K/V (1, Hkv, D, Tc), scales (1, Hkv, C, Tc) dynamic /
+(1, Hkv, C, 1) static, kv_pos (1, 1, Tc); q_pos is a scalar-prefetch
+operand in SMEM. GQA (Hq = G·Hkv) is accumulated in the grouped
+(Hkv, G, ·) layout — K/V are never broadcast to Hq — and both dots batch
+over Hkv with the batch axis leading in both operands. Dot operands are
+fp32, as in the jnp lowering. Chunks whose kv_pos entries are all -1
+(dead slots, unwritten tail) are skipped under `pl.when`: past the
+validity mask they cost no flops, so a 512-deep cache with 100-deep
+occupants does ~1/4 of the work. Fully-empty slots return
+exact 0 (the materialized reference returns a meaningless mean-V row
+there; the engine discards both).
 
-VMEM per program (Tc=128, Hkv=8, D=128, C=4): K+V codes 2·128·8·128 =
-256 KiB int8, scales 2·2·128·8·4·4 = 64 KiB, q/acc 2·Hq·D·4 ≪ 1 MiB —
-well under budget; Tc is the knob if D grows.
+VMEM per program (Tc=128, Hkv=32, D=64, C=4): K+V codes 2·32·64·128 =
+512 KiB int8, scales 4·32·8·128·4 = 512 KiB (C pads to 8 sublanes),
+q/acc ≪ 1 MiB — under budget double-buffered; Tc is the knob if D grows.
 
 The same math ships as a pure-jnp chunked path (`use_pallas=False`, the
 CPU lowering — `jax.lax.cond` gives it the same dead-chunk skip) and the
@@ -75,16 +80,41 @@ def _pick_kv_chunk(T: int, kv_chunk) -> int:
 
 
 # ------------------------------------------------------------- kernel ---
+def to_kernel_layout(x):
+    """(..., T, H, X) → (..., H, X, T): the kernels' time-minor view.
+
+    Time rides the 128-wide lane axis and the head dim the sublanes, so a
+    head_dim or qchunks below 128 never pads a lane tile, a cache chunk is
+    one (H, X, Tc) block, and both attention dots are batched over H with
+    the batch axis leading in both operands (the form Mosaic lowers)."""
+    return jnp.moveaxis(x, -3, -1)
+
+
+def dequant_block(codes, scale, zero):
+    """Kernel-layout dequant: codes (H, D, Tc) int, scale/zero (H, C, Tc)
+    per entry or (H, C, 1) static → fp32 (H, D, Tc).
+
+    The C sub-channel chunks split the SUBLANE axis (D/C rows each, a
+    whole number of f32 tiles whenever D/C % 8 == 0), so the per-chunk
+    constants broadcast along sublanes and time stays on the lanes — no
+    lane-dim reshape. Multiplies by the reciprocal scale: one divide per
+    (H, C, Tc) chunk constant instead of one per element."""
+    H, D, Tc = codes.shape
+    C = scale.shape[1]
+    x = codes.astype(jnp.int32).astype(jnp.float32)
+    x = x.reshape(H, C, D // C, Tc)
+    x = (x - zero[:, :, None, :]) * (1.0 / scale)[:, :, None, :]
+    return x.reshape(H, D, Tc)
+
+
 def _fused_kernel(qpos_ref, q_ref, kpos_ref, k_ref, v_ref, *rest,
-                  mode: str, n_chunks: int, groups: int, per_entry: bool):
+                  mode: str, n_chunks: int):
     if mode == "int8":
         ks_ref, kz_ref, vs_ref, vz_ref, o_ref, m_ref, l_ref, acc_ref = rest
     else:
         o_ref, m_ref, l_ref, acc_ref = rest
-    j = pl.program_id(1)
-    Hq, D = q_ref.shape[1], q_ref.shape[2]
-    Hkv = k_ref.shape[2]
-    G = groups
+    n, j = pl.program_id(0), pl.program_id(1)
+    D = q_ref.shape[-1]
 
     @pl.when(j == 0)
     def _init():
@@ -92,46 +122,40 @@ def _fused_kernel(qpos_ref, q_ref, kpos_ref, k_ref, v_ref, *rest,
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    kpos = kpos_ref[...]                                   # (1, Tc)
-    qpos = qpos_ref[0, 0]
-    valid = (kpos >= 0) & (kpos <= qpos)                   # (1, Tc), causal
+    kpos = kpos_ref[0]                                     # (1, Tc)
+    valid = (kpos >= 0) & (kpos <= qpos_ref[n])            # causal
 
-    @pl.when(jnp.any(valid))
+    @pl.when(jnp.max(valid.astype(jnp.int32)) > 0)
     def _compute():
-        q = q_ref[0].astype(jnp.float32) * (D ** -0.5)     # (Hq, D)
+        q = q_ref[0].astype(jnp.float32) * (D ** -0.5)    # (Hkv, G, D)
         if mode == "int8":
-            # dynamic blocks are (1, Tc, Hkv, C); static (1, 1, Hkv, C)
-            # constants broadcast over the chunk's time axis
-            sel = (lambda r: r[0]) if per_entry else (lambda r: r[0, 0])
-            kc = _dequant_chunk(k_ref[0], sel(ks_ref), sel(kz_ref))
-            vc = _dequant_chunk(v_ref[0], sel(vs_ref), sel(vz_ref))
+            kc = dequant_block(k_ref[0], ks_ref[0], kz_ref[0])
+            vc = dequant_block(v_ref[0], vs_ref[0], vz_ref[0])
         else:
-            kc = k_ref[0].astype(jnp.float32)              # (Tc, Hkv, D)
+            kc = k_ref[0].astype(jnp.float32)              # (Hkv, D, Tc)
             vc = v_ref[0].astype(jnp.float32)
-        qg = q.reshape(Hkv, G, D)
         # scores (Hkv, G, Tc): batch Hkv, contract D — K never expands to Hq
-        s = jax.lax.dot_general(qg, kc, (((2,), (2,)), ((0,), (1,))),
+        s = jax.lax.dot_general(q, kc,
+                                (((2,), (1,)), ((0,), (0,))),
                                 preferred_element_type=jnp.float32)
-        s = s.reshape(Hq, kc.shape[0])
-        s = jnp.where(valid, s, NEG_INF)                   # (Hq, Tc)
-        m_prev = m_ref[:, 0]                               # (Hq,)
-        l_prev = l_ref[:, 0]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1))
+        msk = valid[None]                                  # (1, 1, Tc)
+        s = jnp.where(msk, s, NEG_INF)
+        m_prev = m_ref[...]                                # (Hkv, G, 1)
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
         # exactly-zero weight on invalid entries (matches the reference:
         # exp(NEG_INF - m) underflows to 0 whenever any valid entry exists)
-        p = jnp.where(valid, jnp.exp(s - m_new[:, None]), 0.0)
+        p = jnp.where(msk, jnp.exp(s - m_new), 0.0)
         corr = jnp.exp(m_prev - m_new)
-        l_new = l_prev * corr + jnp.sum(p, axis=-1)
-        pg = p.reshape(Hkv, G, kc.shape[0])
-        pv = jax.lax.dot_general(pg, vc, (((2,), (0,)), ((0,), (1,))),
+        l_ref[...] = l_ref[...] * corr + jnp.sum(p, axis=-1, keepdims=True)
+        pv = jax.lax.dot_general(p, vc,
+                                 (((2,), (2,)), ((0,), (0,))),
                                  preferred_element_type=jnp.float32)
-        acc_ref[...] = acc_ref[...] * corr[:, None] + pv.reshape(Hq, D)
-        m_ref[...] = jnp.broadcast_to(m_new[:, None], m_ref.shape)
-        l_ref[...] = jnp.broadcast_to(l_new[:, None], l_ref.shape)
+        acc_ref[...] = acc_ref[...] * corr + pv            # (Hkv, G, D)
+        m_ref[...] = m_new
 
     @pl.when(j == n_chunks - 1)
     def _flush():
-        l = l_ref[:, :1]                                   # (Hq, 1)
+        l = l_ref[...]
         o = jnp.where(l > 0, acc_ref[...] / jnp.maximum(l, 1e-30), 0.0)
         o_ref[0] = o.astype(o_ref.dtype)
 
@@ -140,40 +164,49 @@ def _decode_attention_pallas(q, k, v, kv_pos, q_pos, scales, *, mode,
                              per_entry, kv_chunk, interpret):
     N, Hq, D = q.shape
     T, Hkv = k.shape[1], k.shape[2]
+    G = Hq // Hkv
     Tc = _pick_kv_chunk(T, kv_chunk)
     nc = T // Tc
-    kernel = functools.partial(_fused_kernel, mode=mode, n_chunks=nc,
-                               groups=Hq // Hkv, per_entry=per_entry)
+    kernel = functools.partial(_fused_kernel, mode=mode, n_chunks=nc)
+    kv_spec = pl.BlockSpec((1, Hkv, D, Tc), lambda n, j, qp: (n, 0, 0, j))
     in_specs = [
-        pl.BlockSpec((1, 1), lambda n, j: (n, 0),
-                     memory_space=pltpu.SMEM),
-        pl.BlockSpec((1, Hq, D), lambda n, j: (n, 0, 0)),
-        pl.BlockSpec((1, Tc), lambda n, j: (n, j)),
-        pl.BlockSpec((1, Tc, Hkv, D), lambda n, j: (n, j, 0, 0)),
-        pl.BlockSpec((1, Tc, Hkv, D), lambda n, j: (n, j, 0, 0)),
+        pl.BlockSpec((1, Hkv, G, D), lambda n, j, qp: (n, 0, 0, 0)),
+        pl.BlockSpec((1, 1, Tc), lambda n, j, qp: (n, 0, j)),
+        kv_spec, kv_spec,
     ]
-    args = [q_pos.reshape(N, 1).astype(jnp.int32), q, kv_pos, k, v]
+    args = [q.reshape(N, Hkv, G, D), kv_pos.reshape(N, 1, T),
+            to_kernel_layout(k), to_kernel_layout(v)]
     if mode == "int8":
         C = scales[0].shape[-1]
         if per_entry:
-            sspec = pl.BlockSpec((1, Tc, Hkv, C), lambda n, j: (n, j, 0, 0))
+            sspec = pl.BlockSpec((1, Hkv, C, Tc),
+                                 lambda n, j, qp: (n, 0, 0, j))
+            args += [to_kernel_layout(s) for s in scales]
         else:
-            sspec = pl.BlockSpec((1, 1, Hkv, C), lambda n, j: (0, 0, 0, 0))
+            # per-layer constants (1, 1, Hkv, C) broadcast over time
+            sspec = pl.BlockSpec((1, Hkv, C, 1),
+                                 lambda n, j, qp: (0, 0, 0, 0))
+            args += [s.reshape(1, Hkv, C, 1) for s in scales]
         in_specs += [sspec] * 4
-        args += list(scales)
-    return pl.pallas_call(
+    out = pl.pallas_call(
         kernel,
-        grid=(N, nc),
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, Hq, D), lambda n, j: (n, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((N, Hq, D), q.dtype),
-        scratch_shapes=[
-            pltpu.VMEM((Hq, 128), jnp.float32),            # running max
-            pltpu.VMEM((Hq, 128), jnp.float32),            # running sum
-            pltpu.VMEM((Hq, D), jnp.float32),              # output acc
-        ],
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(N, nc),
+            in_specs=in_specs,
+            out_specs=pl.BlockSpec((1, Hkv, G, D),
+                                   lambda n, j, qp: (n, 0, 0, 0)),
+            scratch_shapes=[
+                pltpu.VMEM((Hkv, G, 1), jnp.float32),      # running max
+                pltpu.VMEM((Hkv, G, 1), jnp.float32),      # running sum
+                pltpu.VMEM((Hkv, G, D), jnp.float32),      # output acc
+            ]),
+        out_shape=jax.ShapeDtypeStruct((N, Hkv, G, D), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
-    )(*args)
+    )(q_pos.astype(jnp.int32), *args)
+    return out.reshape(N, Hq, D)
 
 
 # ------------------------------------------------- jnp chunked lowering ---

@@ -2,7 +2,8 @@
 
 `linear()` is the single entry point models use: it dispatches on the weight
 leaf type (dense array vs SplitQuantTensor) and on the backend (Pallas TPU
-kernel vs XLA-fused jnp reference — the latter also serves CPU/dry-run).
+kernel vs an XLA-fused jnp dequant-matmul — the latter also serves
+CPU/dry-run, and is what serving runs today).
 """
 from __future__ import annotations
 
@@ -13,13 +14,25 @@ import jax
 import jax.numpy as jnp
 
 from repro.core.splitquant import SplitQuantTensor
-from . import ref
-from .packing import pack_cids, pack_codes
-from .splitquant_matmul import splitquant_matmul
+from .packing import pack_cids, pack_codes, unpack_cids, unpack_codes
+from .splitquant_matmul import select_per_cluster, splitquant_matmul
 
 
 def _round_up(v: int, m: int) -> int:
     return (v + m - 1) // m * m
+
+
+def _xla_matmul(x, q_packed, cid_packed, recip, shift, bits: int, k: int):
+    """The XLA serving path: dequantize the packed weight to x's dtype,
+    selecting each element's cluster constants by a masked sum over the k
+    clusters (a gather of one constant per weight element runs orders of
+    magnitude slower on TPU), then one dense matmul accumulated in fp32.
+    Checked against the plain-gather oracle in `ref`."""
+    q = unpack_codes(q_packed, bits).astype(jnp.float32)          # (K, N)
+    cid = unpack_cids(cid_packed)                                 # (K, N)
+    w = (q * select_per_cluster(recip, cid, k)
+         + select_per_cluster(shift, cid, k)).astype(x.dtype)
+    return jnp.dot(x, w, preferred_element_type=jnp.float32).astype(x.dtype)
 
 
 def dequant_constants(sqt: SplitQuantTensor) -> tuple[jnp.ndarray, jnp.ndarray]:
@@ -60,7 +73,7 @@ def quantized_matmul(x, q_packed, cid_packed, recip, shift, *, bits: int,
     N = q_packed.shape[1]
     x2 = x.reshape(-1, K)
     if not use_pallas:
-        y = ref.splitquant_matmul_ref(x2, q_packed, cid_packed, recip, shift, bits)
+        y = _xla_matmul(x2, q_packed, cid_packed, recip, shift, bits, k)
         return y.reshape(*lead, N)
 
     M = x2.shape[0]

@@ -1,5 +1,5 @@
 """Pallas TPU kernel: fused chunked-prefill attention over the quantized
-slot cache, with quantize-in-kernel K/V writes.
+slot cache, with the chunk's K/V written as codes.
 
 This is the prefill-side twin of `decode_attention`. Before it, every
 admitted request ran the pre-engine prefill: a dense full-precision
@@ -11,12 +11,12 @@ whole prompt length while it happened. Here a prompt is prefilled in
 chunks: one call computes causal self-attention for a chunk of Sq prompt
 tokens of ONE slot against (a) the slot's already-written cache rows
 (INT8 codes dequantized per sub-channel chunk in VMEM, exactly like the
-decode kernel) and (b) the chunk's own full-precision K/V, and in the
-kernel epilogue quantizes the chunk's K/V (SplitQuant §4.2 per-chunk
-ranges — dynamic per-entry, or static per-layer scales from a calibration
-recipe) so the caller scatters the CODES straight into the slot cache's
-storage layout. No (L, S, Hkv, D) fp cache ever exists, and
-`write_prefill`'s pad + requantize + copy disappears.
+decode kernel) and (b) the chunk's own full-precision K/V. Beside the
+call the chunk's K/V are quantized (SplitQuant §4.2 per-chunk ranges —
+dynamic per-entry, or static per-layer scales from a calibration recipe)
+so the caller scatters the CODES straight into the slot cache's storage
+layout. No (L, S, Hkv, D) fp cache ever exists, and `write_prefill`'s
+pad + requantize + copy disappears.
 
 Shapes (one layer, one slot, one chunk):
   q             (Sq, Hq, D)   post-RoPE chunk queries (Sq = padded chunk)
@@ -27,20 +27,24 @@ Shapes (one layer, one slot, one chunk):
   length        scalar        valid tokens in the chunk (rest is padding)
   scales        per-entry (T, Hkv, C) fp32, or static per-layer (Hkv, C)
 
-Grid: (Sq/Bq query blocks, T/Tc cache chunks + 1). The KV sweep (j) is
-fastest: each query block's (m, l, acc) online-softmax state lives in VMEM
-scratch across the sweep. Iterations j < nc stream the slot's CACHE rows —
-valid entries are exactly those with 0 <= kv_pos < pos_start (everything
-earlier than the chunk; rows at >= pos_start are stale or decode-parking
-garbage by the engine's invariants), so no per-query causal test is needed
-and chunks with no valid entry are skipped under `pl.when` (a chunk at
-pos_start=0 skips the whole sweep). The final iteration j == nc attends
+Grid: (Sq/Bq query blocks, T/Tc cache chunks + 1). The kernel sees the
+decode kernel's time-minor layout (`decode_attention.to_kernel_layout`):
+cache blocks (Hkv, D, Tc), queries as (Hkv, Bq·G, D) rows of (query,
+group) pairs, the chunk's K/V as (Hkv, Sq, D), so every dot batches over
+Hkv. The KV sweep (j) is fastest: each query block's (m, l, acc)
+online-softmax state lives in VMEM scratch across the sweep. Iterations
+j < nc stream the slot's CACHE rows — valid entries are exactly those
+with 0 <= kv_pos < pos_start (everything earlier than the chunk; rows at
+>= pos_start are stale or decode-parking garbage by the engine's
+invariants), so no per-query causal test is needed and chunks with no
+valid entry are skipped under `pl.when` (a chunk at pos_start=0 skips the
+whole sweep). The final iteration j == nc attends
 the chunk's own fp K/V under the intra-chunk causal mask
-(key_idx <= query_idx, key_idx < length), flushes the output block, and —
-once, at query block 0 — quantizes the chunk K/V: dynamic mode computes
-per-(token, head, sub-channel-chunk) (β, α) → (S, Z) with the exact
-`core.quantize` eq. (1)-(3) arithmetic (codes are bit-identical to
-`engine.kvcache.quantize_kv`, so chunked and one-shot prefill fill the
+(key_idx <= query_idx, key_idx < length) and flushes the output block.
+The chunk's codes come from the same jnp the one-shot path uses: dynamic
+mode computes per-(token, head, sub-channel-chunk) (β, α) → (S, Z) with
+the exact `core.quantize` eq. (1)-(3) arithmetic (codes are bit-identical
+to `engine.kvcache.quantize_kv`, so chunked and one-shot prefill fill the
 cache with the same bytes); static mode applies recipe constants expanded
 to per-column rows through `act_quant.chunk_id_map` with the exact
 fractional zero-point fold of `quantize_kv_static`.
@@ -67,7 +71,7 @@ is scored in fp), but a verify window must reproduce PLAIN DECODE, and a
 decode step writes its quantized K/V first and then attends the cache —
 i.e. every token sees itself and its in-window predecessors through the
 quantization round-trip. Verify mode therefore quantizes the window K/V
-*first* (the identical arithmetic the epilogue stores) and attends the
+*first* (the identical codes that are stored) and attends the
 dequantized codes under the intra-chunk causal mask; for a float cache
 it round-trips through the cache dtype. Without this, int8 verify logits
 would see fp intra-window K/V that plain decode never sees, and the
@@ -84,7 +88,8 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.quantize import QuantConfig, qparams, quantize, value_range
 
-from .decode_attention import NEG_INF, _dequant_chunk, _pick_kv_chunk
+from .decode_attention import (NEG_INF, _dequant_chunk, _pick_kv_chunk,
+                               dequant_block, to_kernel_layout)
 
 KV_QCFG = QuantConfig(bits=8, symmetric=False)
 
@@ -94,10 +99,9 @@ def _dyn_quantize(x, C):
     """x (S, H, D) fp → (codes int8 (S, H, D), scale/zero fp32 (S, H, C)).
 
     The `engine.kvcache.quantize_kv` composition (value_range → qparams →
-    quantize) — ONE implementation shared by the Pallas epilogue and the
-    jnp lowering (the core ops are pure jnp, so they trace inside the
-    kernel too), keeping chunk codes bit-identical to what the one-shot
-    `write_prefill` path stores by construction."""
+    quantize) — ONE implementation shared by both lowerings, keeping chunk
+    codes bit-identical to what the one-shot `write_prefill` path stores
+    by construction."""
     S, H, D = x.shape
     xc = x.astype(jnp.float32).reshape(S, H, C, D // C)
     beta, alpha = value_range(xc, axis=-1)
@@ -123,24 +127,18 @@ def _dequant_cols(codes, scale_col, zero_col):
 
 # ------------------------------------------------------------- kernel ---
 def _prefill_kernel(info_ref, q_ref, kpos_ref, ck_ref, cv_ref, kn_ref,
-                    vn_ref, *rest, mode: str, per_entry: bool,
-                    n_cache_chunks: int, groups: int, qchunks: int,
-                    verify: bool):
-    if mode == "int8" and per_entry:
-        (ks_ref, kz_ref, vs_ref, vz_ref, o_ref, qk_ref, qv_ref, oks_ref,
-         okz_ref, ovs_ref, ovz_ref, m_ref, l_ref, acc_ref) = rest
-    elif mode == "int8":
-        (ksc_ref, kzc_ref, vsc_ref, vzc_ref, o_ref, qk_ref, qv_ref,
-         m_ref, l_ref, acc_ref) = rest
+                    vn_ref, *rest, mode: str, n_cache_chunks: int,
+                    groups: int):
+    if mode == "int8":
+        ks_ref, kz_ref, vs_ref, vz_ref, o_ref, m_ref, l_ref, acc_ref = rest
     else:
         o_ref, m_ref, l_ref, acc_ref = rest
     i, j = pl.program_id(0), pl.program_id(1)
     nc = n_cache_chunks
-    Bq, Hq, D = q_ref.shape
-    Hkv = ck_ref.shape[1]
+    Hkv, M, D = q_ref.shape                  # M = Bq·G rows, (query, group)
     G = groups
-    pos_start = info_ref[0, 0]
-    length = info_ref[0, 1]
+    pos_start = info_ref[0]
+    length = info_ref[1]
 
     @pl.when(j == 0)
     def _init():
@@ -148,25 +146,19 @@ def _prefill_kernel(info_ref, q_ref, kpos_ref, ck_ref, cv_ref, kn_ref,
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    # grouped (Hkv, Bq, G, ·) layout throughout — K/V never expand to Hq
-    qg = (q_ref[...].astype(jnp.float32) * (D ** -0.5)).reshape(
-        Bq, Hkv, G, D)
+    # grouped (Hkv, Bq·G, ·) layout throughout — K/V never expand to Hq
+    q = q_ref[...].astype(jnp.float32) * (D ** -0.5)
 
-    def online_update(kc, vc, valid):
-        """kc/vc (Tk, Hkv, D) fp32, valid (Bq|1, Tk) → scratch update."""
-        s = jax.lax.dot_general(qg, kc, (((3,), (2,)), ((1,), (1,))),
-                                preferred_element_type=jnp.float32)
-        # s: (Hkv, Bq, G, Tk)
-        msk = valid[None, :, None, :]
-        s = jnp.where(msk, s, NEG_INF)
+    def online_update(s, valid, pv_of):
+        """s (Hkv, M, Tk) scores, valid broadcastable to s, pv_of(p) the
+        (Hkv, M, D) probability-weighted values → scratch update."""
+        s = jnp.where(valid, s, NEG_INF)
         m_prev = m_ref[...]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1))
-        p = jnp.where(msk, jnp.exp(s - m_new[..., None]), 0.0)
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+        p = jnp.where(valid, jnp.exp(s - m_new), 0.0)
         corr = jnp.exp(m_prev - m_new)
-        l_ref[...] = l_ref[...] * corr + jnp.sum(p, axis=-1)
-        pv = jax.lax.dot_general(p, vc, (((3,), (0,)), ((0,), (1,))),
-                                 preferred_element_type=jnp.float32)
-        acc_ref[...] = acc_ref[...] * corr[..., None] + pv
+        l_ref[...] = l_ref[...] * corr + jnp.sum(p, axis=-1, keepdims=True)
+        acc_ref[...] = acc_ref[...] * corr + pv_of(p)
         m_ref[...] = m_new
 
     kpos = kpos_ref[...]                                   # (1, Tc)
@@ -175,79 +167,45 @@ def _prefill_kernel(info_ref, q_ref, kpos_ref, ck_ref, cv_ref, kn_ref,
     # decode-parking garbage, and the chunk's own K/V arrive via kn/vn
     cache_valid = (kpos >= 0) & (kpos < pos_start)
 
-    @pl.when((j < nc) & jnp.any(cache_valid))
+    @pl.when((j < nc) & (jnp.max(cache_valid.astype(jnp.int32)) > 0))
     def _cache_chunk():
         if mode == "int8":
-            if per_entry:
-                kc = _dequant_chunk(ck_ref[...], ks_ref[...], kz_ref[...])
-                vc = _dequant_chunk(cv_ref[...], vs_ref[...], vz_ref[...])
-            else:
-                kc = _dequant_cols(ck_ref[...], ksc_ref[...], kzc_ref[...])
-                vc = _dequant_cols(cv_ref[...], vsc_ref[...], vzc_ref[...])
+            kc = dequant_block(ck_ref[...], ks_ref[...], kz_ref[...])
+            vc = dequant_block(cv_ref[...], vs_ref[...], vz_ref[...])
         else:
-            kc = ck_ref[...].astype(jnp.float32)
+            kc = ck_ref[...].astype(jnp.float32)           # (Hkv, D, Tc)
             vc = cv_ref[...].astype(jnp.float32)
-        online_update(kc, vc, cache_valid)
+        s = jax.lax.dot_general(q, kc,
+                                (((2,), (1,)), ((0,), (0,))),
+                                preferred_element_type=jnp.float32)
+        online_update(s, cache_valid[None], lambda p: jax.lax.dot_general(
+            p, vc, (((2,), (2,)), ((0,), (0,))),
+            preferred_element_type=jnp.float32))
 
     @pl.when(j == nc)
     def _chunk_and_flush():
-        Sq = kn_ref.shape[0]
-        qidx = i * Bq + jax.lax.broadcasted_iota(jnp.int32, (Bq, Sq), 0)
-        cidx = jax.lax.broadcasted_iota(jnp.int32, (Bq, Sq), 1)
-        valid = (cidx <= qidx) & (cidx < length)           # (Bq, Sq) causal
-        kn = kn_ref[...].astype(jnp.float32)
+        Sq = kn_ref.shape[1]
+        row = jax.lax.broadcasted_iota(jnp.int32, (M, Sq), 0)
+        if G > 1:                            # rows are (query, group)
+            row = jax.lax.div(row, G)
+        qidx = i * (M // G) + row
+        cidx = jax.lax.broadcasted_iota(jnp.int32, (M, Sq), 1)
+        valid = (cidx <= qidx) & (cidx < length)           # causal
+        kn = kn_ref[...].astype(jnp.float32)               # (Hkv, Sq, D)
         vn = vn_ref[...].astype(jnp.float32)
-        if verify:
-            # speculative verify: attend the window's own K/V through the
-            # SAME storage round-trip a decode-step write applies (codes
-            # are what a plain-decode successor would have attended), so
-            # the accept rule compares against plain-decode logits
-            if mode == "int8" and per_entry:
-                q8, s, z = _dyn_quantize(kn_ref[...], qchunks)
-                kn = _dequant_chunk(q8, s, z)
-                q8, s, z = _dyn_quantize(vn_ref[...], qchunks)
-                vn = _dequant_chunk(q8, s, z)
-            elif mode == "int8":
-                kn = _dequant_cols(_static_quantize_cols(
-                    kn_ref[...], ksc_ref[...], kzc_ref[...]),
-                    ksc_ref[...], kzc_ref[...])
-                vn = _dequant_cols(_static_quantize_cols(
-                    vn_ref[...], vsc_ref[...], vzc_ref[...]),
-                    vsc_ref[...], vzc_ref[...])
-            else:
-                kn = kn_ref[...].astype(ck_ref.dtype).astype(jnp.float32)
-                vn = vn_ref[...].astype(cv_ref.dtype).astype(jnp.float32)
-        online_update(kn, vn, valid)
+        s = jax.lax.dot_general(q, kn, (((2,), (2,)), ((0,), (0,))),
+                                preferred_element_type=jnp.float32)
+        online_update(s, valid[None], lambda p: jax.lax.dot_general(
+            p, vn, (((2,), (1,)), ((0,), (0,))),
+            preferred_element_type=jnp.float32))
         l = l_ref[...]
-        o = jnp.where(l[..., None] > 0,
-                      acc_ref[...] / jnp.maximum(l, 1e-30)[..., None], 0.0)
-        # (Hkv, Bq, G, D) → (Bq, Hq, D)
-        o_ref[...] = o.transpose(1, 0, 2, 3).reshape(Bq, Hq, D).astype(
-            o_ref.dtype)
-
-    if mode == "int8":
-        # epilogue: quantize the chunk's K/V once (query block 0) so the
-        # caller scatters codes straight into the slot cache layout
-        @pl.when((j == nc) & (i == 0))
-        def _quantize_chunk():
-            if per_entry:
-                for x_ref, cq_ref, cs_ref, cz_ref in (
-                        (kn_ref, qk_ref, oks_ref, okz_ref),
-                        (vn_ref, qv_ref, ovs_ref, ovz_ref)):
-                    q8, s, z = _dyn_quantize(x_ref[...], qchunks)
-                    cq_ref[...] = q8
-                    cs_ref[...] = s
-                    cz_ref[...] = z
-            else:
-                qk_ref[...] = _static_quantize_cols(
-                    kn_ref[...], ksc_ref[...], kzc_ref[...])
-                qv_ref[...] = _static_quantize_cols(
-                    vn_ref[...], vsc_ref[...], vzc_ref[...])
+        o = jnp.where(l > 0, acc_ref[...] / jnp.maximum(l, 1e-30), 0.0)
+        o_ref[...] = o.astype(o_ref.dtype)
 
 
 def _prefill_attention_pallas(q, k_new, v_new, cache_k, cache_v, kv_pos,
                               pos_start, length, scales, *, mode, per_entry,
-                              kv_chunk, q_block, interpret, verify=False):
+                              kv_chunk, q_block, interpret):
     Sq, Hq, D = q.shape
     T, Hkv = cache_k.shape[0], cache_k.shape[1]
     Tc = _pick_kv_chunk(T, kv_chunk)
@@ -255,67 +213,61 @@ def _prefill_attention_pallas(q, k_new, v_new, cache_k, cache_v, kv_pos,
     Bq = _pick_kv_chunk(Sq, 128 if q_block is None else q_block)
     nq = Sq // Bq
     G = Hq // Hkv
-    C = scales[0].shape[-1] if (mode == "int8" and per_entry) else 0
-    qchunks = C if per_entry else (scales[0].shape[-1] if mode == "int8"
-                                   else 0)
+    M = Bq * G
     jc = lambda j: jnp.minimum(j, nc - 1)      # clamp: block unused at j=nc
-    info = jnp.asarray([[pos_start, length]], jnp.int32)
+    info = jnp.stack([jnp.asarray(pos_start, jnp.int32),
+                      jnp.asarray(length, jnp.int32)])
+    # heads lead, time on lanes (decode_attention.to_kernel_layout); query
+    # rows are (query, group) pairs so every dot batches over Hkv
+    qh = q.reshape(Sq, Hkv, G, D).transpose(1, 0, 2, 3).reshape(
+        Hkv, Sq * G, D)
+    new_spec = pl.BlockSpec((Hkv, Sq, D), lambda i, j, info: (0, 0, 0))
+    kv_spec = pl.BlockSpec((Hkv, D, Tc), lambda i, j, info: (0, 0, jc(j)))
     in_specs = [
-        pl.BlockSpec((1, 2), lambda i, j: (0, 0), memory_space=pltpu.SMEM),
-        pl.BlockSpec((Bq, Hq, D), lambda i, j: (i, 0, 0)),
-        pl.BlockSpec((1, Tc), lambda i, j: (0, jc(j))),
-        pl.BlockSpec((Tc, Hkv, D), lambda i, j: (jc(j), 0, 0)),
-        pl.BlockSpec((Tc, Hkv, D), lambda i, j: (jc(j), 0, 0)),
-        pl.BlockSpec((Sq, Hkv, D), lambda i, j: (0, 0, 0)),
-        pl.BlockSpec((Sq, Hkv, D), lambda i, j: (0, 0, 0)),
+        pl.BlockSpec((Hkv, M, D), lambda i, j, info: (0, i, 0)),
+        pl.BlockSpec((1, Tc), lambda i, j, info: (0, jc(j))),
+        kv_spec, kv_spec, new_spec, new_spec,
     ]
-    args = [info, q, kv_pos.reshape(1, T).astype(jnp.int32),
-            cache_k, cache_v, k_new, v_new]
-    out_specs = [pl.BlockSpec((Bq, Hq, D), lambda i, j: (i, 0, 0))]
-    out_shape = [jax.ShapeDtypeStruct((Sq, Hq, D), q.dtype)]
+    args = [qh, kv_pos.reshape(1, T).astype(jnp.int32),
+            to_kernel_layout(cache_k), to_kernel_layout(cache_v),
+            k_new.transpose(1, 0, 2), v_new.transpose(1, 0, 2)]
     if mode == "int8":
+        C = scales[0].shape[-1]
         if per_entry:
-            sspec = pl.BlockSpec((Tc, Hkv, C), lambda i, j: (jc(j), 0, 0))
-            in_specs += [sspec] * 4
-            args += list(scales)
-            code_spec = pl.BlockSpec((Sq, Hkv, D), lambda i, j: (0, 0, 0))
-            cs_spec = pl.BlockSpec((Sq, Hkv, C), lambda i, j: (0, 0, 0))
-            out_specs += [code_spec] * 2 + [cs_spec] * 4
-            out_shape += [jax.ShapeDtypeStruct((Sq, Hkv, D), jnp.int8)] * 2
-            out_shape += [jax.ShapeDtypeStruct((Sq, Hkv, C),
-                                               jnp.float32)] * 4
+            in_specs += [pl.BlockSpec((Hkv, C, Tc),
+                                      lambda i, j, info: (0, 0, jc(j)))] * 4
+            args += [to_kernel_layout(s) for s in scales]
         else:
-            # static: per-column (Hkv, D) rows expanded via chunk_id_map —
-            # one broadcast multiply serves cache dequant AND the epilogue
-            sspec = pl.BlockSpec((Hkv, D), lambda i, j: (0, 0))
-            in_specs += [sspec] * 4
-            args += list(scales)
-            code_spec = pl.BlockSpec((Sq, Hkv, D), lambda i, j: (0, 0, 0))
-            out_specs += [code_spec] * 2
-            out_shape += [jax.ShapeDtypeStruct((Sq, Hkv, D), jnp.int8)] * 2
-    kernel = functools.partial(
-        _prefill_kernel, mode=mode, per_entry=per_entry, n_cache_chunks=nc,
-        groups=G, qchunks=qchunks, verify=verify)
-    outs = pl.pallas_call(
+            # per-layer constants (Hkv, C) broadcast over time
+            in_specs += [pl.BlockSpec((Hkv, C, 1),
+                                      lambda i, j, info: (0, 0, 0))] * 4
+            args += [s.reshape(Hkv, C, 1) for s in scales]
+    kernel = functools.partial(_prefill_kernel, mode=mode,
+                               n_cache_chunks=nc, groups=G)
+    o = pl.pallas_call(
         kernel,
-        grid=(nq, nc + 1),
-        in_specs=in_specs,
-        out_specs=out_specs,
-        out_shape=out_shape,
-        scratch_shapes=[
-            pltpu.VMEM((Hkv, Bq, G), jnp.float32),         # running max
-            pltpu.VMEM((Hkv, Bq, G), jnp.float32),         # running sum
-            pltpu.VMEM((Hkv, Bq, G, D), jnp.float32),      # output acc
-        ],
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(nq, nc + 1),
+            in_specs=in_specs,
+            out_specs=pl.BlockSpec((Hkv, M, D), lambda i, j, info: (0, i, 0)),
+            scratch_shapes=[
+                pltpu.VMEM((Hkv, M, 1), jnp.float32),      # running max
+                pltpu.VMEM((Hkv, M, 1), jnp.float32),      # running sum
+                pltpu.VMEM((Hkv, M, D), jnp.float32),      # output acc
+            ]),
+        out_shape=jax.ShapeDtypeStruct((Hkv, Sq * G, D), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
-    )(*args)
-    return outs[0], tuple(outs[1:])
+    )(info, *args)
+    return o.reshape(Hkv, Sq, G, D).transpose(1, 0, 2, 3).reshape(Sq, Hq, D)
 
 
 # ------------------------------------------------- jnp chunked lowering ---
 def _prefill_attention_jnp(q, k_new, v_new, cache_k, cache_v, kv_pos,
                            pos_start, length, scales, *, mode, per_entry,
-                           kv_chunk, verify=False):
+                           kv_chunk):
     """Same online-softmax sweep in pure jnp — the CPU path. `lax.cond`
     skips cache chunks with no valid entry (lazy `dynamic_slice` inside
     the branch, so skipped codes never move), then a final step attends
@@ -378,26 +330,8 @@ def _prefill_attention_jnp(q, k_new, v_new, cache_k, cache_v, kv_pos,
     qidx = jnp.arange(Sq, dtype=jnp.int32)
     cidx = jnp.arange(Sq, dtype=jnp.int32)
     valid = (cidx[None, :] <= qidx[:, None]) & (cidx[None, :] < length)
-    kn = k_new.astype(jnp.float32)
-    vn = v_new.astype(jnp.float32)
-    if verify:
-        # same storage round-trip as the Pallas verify branch: the window
-        # attends itself exactly as a plain decode step would (quantized
-        # codes for int8 caches, cache-dtype cast for float caches)
-        if mode == "int8" and per_entry:
-            q8, s, z = _dyn_quantize(k_new, scales[0].shape[-1])
-            kn = _dequant_chunk(q8, s, z)
-            q8, s, z = _dyn_quantize(v_new, scales[0].shape[-1])
-            vn = _dequant_chunk(q8, s, z)
-        elif mode == "int8":
-            kn = _dequant_cols(_static_quantize_cols(
-                k_new, scales[0], scales[1]), scales[0], scales[1])
-            vn = _dequant_cols(_static_quantize_cols(
-                v_new, scales[2], scales[3]), scales[2], scales[3])
-        else:
-            kn = k_new.astype(cache_k.dtype).astype(jnp.float32)
-            vn = v_new.astype(cache_v.dtype).astype(jnp.float32)
-    m, l, acc = update(carry, kn, vn, valid)
+    m, l, acc = update(carry, k_new.astype(jnp.float32),
+                       v_new.astype(jnp.float32), valid)
     o = jnp.where(l[..., None] > 0, acc / jnp.maximum(l, 1e-30)[..., None],
                   0.0)
     return o.reshape(Sq, Hq, D).astype(q.dtype)
@@ -443,30 +377,43 @@ def prefill_attention(q, k_new, v_new, cache_k, cache_v, kv_pos, pos_start,
         if D % C:
             raise ValueError(f"head_dim {D} not divisible by qchunks {C}")
         if not per_entry_scales:
-            # expand static (Hkv, C) recipe constants to per-column rows —
-            # act_quant's chunk-id map, reused at the head-dim granularity
+            # static (Hkv, C) recipe constants expanded to per-column rows
+            # (act_quant's chunk-id map at head-dim granularity) for the
+            # chunk's quantization and the jnp sweep's cache dequant
             from .act_quant import chunk_id_map
             cid = jnp.asarray(chunk_id_map(D, C))
-            scales = tuple(jnp.take(s.astype(jnp.float32), cid, axis=-1)
-                           for s in scales)              # 4 × (Hkv, D)
+            scales = tuple(s.astype(jnp.float32) for s in scales)
+            cols = tuple(jnp.take(s, cid, axis=-1) for s in scales)
+    # the chunk's storage form — codes (+ fresh dynamic scales) for an
+    # int8 cache — is computed once here for both lowerings; a verify
+    # window attends its own K/V through that same round-trip
+    codes = ()
+    kn, vn = k_new, v_new
+    if mode == "int8" and per_entry_scales:
+        qk, ks, kz = _dyn_quantize(k_new, C)
+        qv, vs, vz = _dyn_quantize(v_new, C)
+        codes = (qk, qv, ks, kz, vs, vz)
+        if verify:
+            kn, vn = _dequant_chunk(qk, ks, kz), _dequant_chunk(qv, vs, vz)
+    elif mode == "int8":
+        qk = _static_quantize_cols(k_new, cols[0], cols[1])
+        qv = _static_quantize_cols(v_new, cols[2], cols[3])
+        codes = (qk, qv)
+        if verify:
+            kn = _dequant_cols(qk, cols[0], cols[1])
+            vn = _dequant_cols(qv, cols[2], cols[3])
+    elif verify:
+        kn, vn = k_new.astype(cache_k.dtype), v_new.astype(cache_v.dtype)
     if use_pallas is None:
         use_pallas = jax.default_backend() == "tpu"
     if use_pallas:
-        return _prefill_attention_pallas(
-            q, k_new, v_new, cache_k, cache_v, kv_pos, pos_start, length,
-            scales, mode=mode, per_entry=per_entry_scales,
-            kv_chunk=kv_chunk, q_block=q_block, interpret=interpret,
-            verify=verify)
-    o = _prefill_attention_jnp(
-        q, k_new, v_new, cache_k, cache_v, kv_pos, pos_start, length,
-        scales, mode=mode, per_entry=per_entry_scales, kv_chunk=kv_chunk,
-        verify=verify)
-    if mode != "int8":
-        return o, ()
-    if per_entry_scales:
-        qk, ks, kz = _dyn_quantize(k_new, C)
-        qv, vs, vz = _dyn_quantize(v_new, C)
-        return o, (qk, qv, ks, kz, vs, vz)
-    qk = _static_quantize_cols(k_new, scales[0], scales[1])
-    qv = _static_quantize_cols(v_new, scales[2], scales[3])
-    return o, (qk, qv)
+        o = _prefill_attention_pallas(
+            q, kn, vn, cache_k, cache_v, kv_pos, pos_start, length, scales,
+            mode=mode, per_entry=per_entry_scales, kv_chunk=kv_chunk,
+            q_block=q_block, interpret=interpret)
+    else:
+        o = _prefill_attention_jnp(
+            q, kn, vn, cache_k, cache_v, kv_pos, pos_start, length,
+            cols if (mode == "int8" and not per_entry_scales) else scales,
+            mode=mode, per_entry=per_entry_scales, kv_chunk=kv_chunk)
+    return o, codes

@@ -25,13 +25,19 @@ from jax.experimental.pallas import tpu as pltpu
 from .packing import unpack_cids, unpack_codes
 
 
-def _select_per_cluster(vals: jnp.ndarray, cid: jnp.ndarray, k: int) -> jnp.ndarray:
-    """vals: (k, bn) per-cluster constants; cid: (bk, bn) → (bk, bn).
-    k is static and tiny (≤4), so an unrolled masked sum beats a gather on
-    the VPU (no dynamic addressing)."""
+def select_per_cluster(vals: jnp.ndarray, cid: jnp.ndarray,
+                       k: int) -> jnp.ndarray:
+    """vals: (k, N) per-cluster constants; cid: (K, N) → (K, N).
+    k is static and tiny (≤4), so an unrolled masked sum beats a gather:
+    no dynamic addressing on the VPU, and in XLA an elementwise fusion
+    where a per-element gather runs orders of magnitude slower on TPU.
+    The ids are widened to int32 and each mask becomes an f32 multiplier:
+    Mosaic cannot relayout the i1 mask a uint8 compare yields into a
+    `where` against a broadcast row."""
+    cid = cid.astype(jnp.int32)
     out = jnp.zeros(cid.shape, jnp.float32)
     for c in range(k):
-        out = out + jnp.where(cid == c, vals[c][None, :], 0.0)
+        out = out + (cid == c).astype(jnp.float32) * vals[c:c + 1, :]
     return out
 
 
@@ -43,8 +49,8 @@ def _kernel(x_ref, qp_ref, cp_ref, recip_ref, shift_ref, o_ref, acc_ref,
 
     q = unpack_codes(qp_ref[...], bits).astype(jnp.float32)      # (bk, bn)
     cid = unpack_cids(cp_ref[...])                                # (bk, bn)
-    recip = _select_per_cluster(recip_ref[...], cid, k)
-    shift = _select_per_cluster(shift_ref[...], cid, k)
+    recip = select_per_cluster(recip_ref[...], cid, k)
+    shift = select_per_cluster(shift_ref[...], cid, k)
     w = (q * recip + shift).astype(x_ref.dtype)                  # dequant in VMEM
     acc_ref[...] += jnp.dot(x_ref[...], w,
                             preferred_element_type=jnp.float32)

@@ -210,7 +210,7 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool, quantized: bool,
     mesh = make_production_mesh(multi_pod=multi_pod)
     t0 = time.perf_counter()
     try:
-        with mesh:
+        with jax.set_mesh(mesh):
             fn, args = build_step(cfg, shape_name, mesh, quantized,
                                   opt_dtype=opt_dtype, bits=bits,
                                   kv_chunk_train=kv_chunk_train,

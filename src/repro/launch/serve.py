@@ -34,6 +34,7 @@ from repro.engine import (Engine, EngineConfig, FaultSpec, InjectedCrash,
                           admission_set_point, occupied_slots)
 from repro.models import get_model
 from repro.runtime.serve_loop import Request, ServeConfig, Server
+from .compile_cache import use_compile_cache
 
 
 def load_recipe_params(recipe_dir, params, arch=None, reduced=None):
@@ -297,6 +298,7 @@ def main():
                     help="run offline calibration, write recipe + "
                          "quantized ckpt to this dir, and exit")
     args = ap.parse_args()
+    use_compile_cache()
 
     cfg = get_arch(args.arch)
     if args.reduced:

@@ -20,6 +20,7 @@ from repro.data import DataConfig, Prefetcher, synthetic_lm_batch
 from repro.models import get_model
 from repro.optim import adamw
 from repro.runtime import train_loop
+from .compile_cache import use_compile_cache
 from .mesh import make_local_mesh, make_production_mesh
 from .shardings import batch_shardings, opt_shardings, param_shardings
 
@@ -39,6 +40,7 @@ def main():
     ap.add_argument("--opt-dtype", default="float32")
     ap.add_argument("--production-mesh", action="store_true")
     args = ap.parse_args()
+    use_compile_cache()
 
     cfg = get_arch(args.arch)
     if args.reduced:
@@ -56,7 +58,7 @@ def main():
     def loss_fn(p, b):
         return model.loss_fn(p, cfg, b, remat=True)
 
-    with mesh:
+    with jax.set_mesh(mesh):
         params = model.init(key, cfg)
         p_sh = param_shardings(params, mesh)
         params = jax.device_put(params, p_sh)

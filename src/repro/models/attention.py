@@ -142,11 +142,9 @@ def tshard_decode_attend(q, k, v, q_pos, kv_pos, *, window=None):
     q: (B, 1, Hq, D) — heads REPLICATED over "model" (q is tiny at decode);
     k, v: (B, T, Hkv, D) with T sharded over "model"; kv_pos: (T,).
     """
-    from jax._src import mesh as _mesh_lib
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
-    mesh = _mesh_lib.thread_resources.env.physical_mesh
+    mesh = jax.sharding.get_abstract_mesh()
     if mesh.empty or "model" not in mesh.axis_names or kv_pos.ndim > 1:
         # batched (per-request) kv_pos carries no single time shard; the
         # engine path never runs time-sharded, so fall back
@@ -182,13 +180,13 @@ def tshard_decode_attend(q, k, v, q_pos, kv_pos, *, window=None):
         acc_g = jax.lax.psum(acc * corr[..., None], "model")
         return (acc_g / jnp.maximum(l_g, 1e-30)[..., None]).astype(qb.dtype)
 
-    fn = shard_map(body, mesh=mesh,
-                   in_specs=(P(bspec, None, None, None),
-                             P(bspec, "model", None, None),
-                             P(bspec, "model", None, None),
-                             P("model"), P(None)),
-                   out_specs=P(bspec, None, None, None),
-                   check_rep=False)
+    fn = jax.shard_map(body, mesh=mesh,
+                       in_specs=(P(bspec, None, None, None),
+                                 P(bspec, "model", None, None),
+                                 P(bspec, "model", None, None),
+                                 P("model"), P(None)),
+                       out_specs=P(bspec, None, None, None),
+                       check_vma=False)
     return fn(q, k, v, kv_pos, q_pos)
 
 

@@ -34,17 +34,10 @@ def shard_hint(x, *spec):
     """
     if not _HINTS_ON:
         return x
-    try:
-        from jax._src import mesh as _mesh_lib
-        mesh = _mesh_lib.thread_resources.env.physical_mesh
-        if mesh.empty:
-            mesh = jax.sharding.get_abstract_mesh()
-        names = getattr(mesh, "axis_names", None)
-        if not names:
-            return x
-        axis_size = dict(mesh.shape)
-    except Exception:
+    mesh = jax.sharding.get_abstract_mesh()
+    if mesh.empty:
         return x
+    axis_size = dict(mesh.shape)
 
     def resolve(ax):
         if ax is None:
@@ -91,18 +84,13 @@ def tp_dense(x, w, b=None):
     Falls back to `dense` when no mesh is active, dims don't divide, the
     weight is quantized/stacked oddly, or a bias is present.
     """
-    from jax._src import mesh as _mesh_lib
     if (not _HINTS_ON or b is not None or
             isinstance(w, SplitQuantTensor) or w.ndim != 2 or x.ndim < 2):
         return dense(x, w, b)
-    try:
-        mesh = _mesh_lib.thread_resources.env.physical_mesh
-    except Exception:
-        return dense(x, w, b)
+    mesh = jax.sharding.get_abstract_mesh()
     if mesh.empty or "model" not in mesh.axis_names:
         return dense(x, w, b)
     import math
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as _P
     sizes = dict(mesh.shape)
     tp = sizes["model"]
@@ -125,8 +113,8 @@ def tp_dense(x, w, b=None):
                  (None,) * (x.ndim - 2) + ("model",)))
     wspec = _P("model", fsdp)
     ospec = _P(*((dp_axes if dp_axes else None,) + (None,) * (x.ndim - 1)))
-    fn = shard_map(body, mesh=mesh, in_specs=(xspec, wspec),
-                   out_specs=ospec, check_rep=False)
+    fn = jax.shard_map(body, mesh=mesh, in_specs=(xspec, wspec),
+                       out_specs=ospec, check_vma=False)
     return fn(x, w)
 
 

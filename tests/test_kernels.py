@@ -64,6 +64,21 @@ def test_kernel_matches_ref(bits, shape, dtype):
                                rtol=tol, atol=tol)
 
 
+@pytest.mark.parametrize("bits", [2, 4, 8])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_xla_path_matches_ref(bits, dtype):
+    """The serving path off the Pallas kernel (masked-sum cluster select)
+    against the oracle's plain per-element gather."""
+    (qp, cp, recip, shift), _ = _packed(KEY, 512, 256, bits)
+    x = jax.random.normal(KEY, (8, 512), dtype=dtype)
+    y_ref = ref.splitquant_matmul_ref(x, qp, cp, recip, shift, bits)
+    y_xla = ops.quantized_matmul(x, qp, cp, recip, shift, bits=bits, k=3)
+    tol = 2e-2 if dtype == jnp.bfloat16 else 2e-5
+    np.testing.assert_allclose(np.asarray(y_xla, np.float32),
+                               np.asarray(y_ref, np.float32),
+                               rtol=tol, atol=tol)
+
+
 @pytest.mark.parametrize("bits", [2, 4])
 def test_kernel_matches_paper_three_layer_form(bits):
     (qp, cp, recip, shift), _ = _packed(KEY, 512, 256, bits)

@@ -30,8 +30,9 @@ def run_sub(code: str):
 def test_tshard_ring_decode_matches_dense():
     out = run_sub("""
         import jax, jax.numpy as jnp, numpy as np
+        from repro.launch.mesh import make_mesh
         from repro.models.attention import attend, tshard_decode_attend
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = make_mesh((2, 4), ("data", "model"))
         key = jax.random.PRNGKey(0)
         B, T, Hq, Hkv, D = 4, 32, 8, 2, 16
         q = jax.random.normal(key, (B, 1, Hq, D))
@@ -40,7 +41,7 @@ def test_tshard_ring_decode_matches_dense():
         kv_pos = jnp.where(jnp.arange(T) < 20, jnp.arange(T), -1)
         q_pos = jnp.asarray([19])
         ref = attend(q, k, v, q_pos, kv_pos, causal=True)
-        with mesh:
+        with jax.set_mesh(mesh):
             ring = jax.jit(lambda *a: tshard_decode_attend(*a))(
                 q, k, v, q_pos, kv_pos)
         err = float(jnp.abs(ring - ref).max())
@@ -57,6 +58,7 @@ def test_sharded_quantized_serve_matches_unsharded():
         from repro.configs import get_arch
         from repro.models import get_model
         from repro.core import QuantConfig, QuantPolicy, quantize_tree
+        from repro.launch.mesh import make_mesh
         from repro.launch.shardings import param_shardings, cache_shardings
         cfg = get_arch("chatglm3-6b").reduced()   # GQA kv=2 < tp
         model = get_model(cfg)
@@ -66,8 +68,8 @@ def test_sharded_quantized_serve_matches_unsharded():
         toks = jax.random.randint(key, (8, 8), 0, cfg.vocab)
         logits0, cache = model.prefill(qp, cfg, {"tokens": toks}, max_len=16)
         ref, _ = model.decode_step(qp, cfg, cache, toks[:, :1], jnp.int32(8))
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
-        with mesh:
+        mesh = make_mesh((2, 4), ("data", "model"))
+        with jax.set_mesh(mesh):
             p_sh = param_shardings(qp, mesh, fsdp=False)
             c_sh = cache_shardings(cache, mesh)
             qp_s = jax.device_put(qp, p_sh)
@@ -87,12 +89,13 @@ def test_sharded_quantized_serve_matches_unsharded():
 def test_tp_dense_matches_dense():
     out = run_sub("""
         import jax, jax.numpy as jnp
+        from repro.launch.mesh import make_mesh
         from repro.models.common import tp_dense, dense
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = make_mesh((2, 4), ("data", "model"))
         key = jax.random.PRNGKey(0)
         x = jax.random.normal(key, (8, 6, 32))
         w = jax.random.normal(jax.random.fold_in(key, 1), (32, 16))
-        with mesh:
+        with jax.set_mesh(mesh):
             got = jax.jit(lambda x, w: tp_dense(x, w))(x, w)
         ref = dense(x, w)
         err = float(jnp.abs(got - ref).max())
@@ -108,6 +111,7 @@ def test_sharded_train_step_matches_unsharded_loss():
         import jax, jax.numpy as jnp
         from repro.configs import get_arch
         from repro.models import get_model
+        from repro.launch.mesh import make_mesh
         from repro.launch.shardings import param_shardings, batch_shardings
         cfg = get_arch("moonshot-v1-16b-a3b").reduced()
         model = get_model(cfg)
@@ -116,8 +120,8 @@ def test_sharded_train_step_matches_unsharded_loss():
         batch = {"tokens": jax.random.randint(key, (8, 16), 0, cfg.vocab),
                  "labels": jax.random.randint(key, (8, 16), 0, cfg.vocab)}
         ref, _ = model.loss_fn(params, cfg, batch)
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
-        with mesh:
+        mesh = make_mesh((2, 4), ("data", "model"))
+        with jax.set_mesh(mesh):
             p_sh = param_shardings(params, mesh)
             b_sh = batch_shardings(batch, mesh)
             p = jax.device_put(params, p_sh)
@@ -141,6 +145,7 @@ def test_elastic_restart_across_mesh_shapes():
         from repro.models import get_model
         from repro.optim import adamw
         from repro.checkpoint import ckpt
+        from repro.launch.mesh import make_mesh
         from repro.launch.shardings import param_shardings, batch_shardings, opt_shardings
         from repro.data import DataConfig, synthetic_lm_batch
 
@@ -159,8 +164,8 @@ def test_elastic_restart_across_mesh_shapes():
             return jax.jit(step, in_shardings=(p_sh, o_sh, b_sh))
 
         with tempfile.TemporaryDirectory() as d:
-            mesh1 = jax.make_mesh((1, 8), ("data", "model"))
-            with mesh1:
+            mesh1 = make_mesh((1, 8), ("data", "model"))
+            with jax.set_mesh(mesh1):
                 params = model.init(key, cfg)
                 p_sh = param_shardings(params, mesh1)
                 params = jax.device_put(params, p_sh)
@@ -174,8 +179,8 @@ def test_elastic_restart_across_mesh_shapes():
                 loss_mesh1 = float(step(params, opt, jax.device_put(synthetic_lm_batch(dc, 3), b_sh))[2])
 
             # "new fleet": different mesh shape
-            mesh2 = jax.make_mesh((2, 4), ("data", "model"))
-            with mesh2:
+            mesh2 = make_mesh((2, 4), ("data", "model"))
+            with jax.set_mesh(mesh2):
                 like = (model.init(key, cfg), adamw.init(opt_cfg, model.init(key, cfg)))
                 p_sh2 = param_shardings(like[0], mesh2)
                 o_sh2 = opt_shardings(like[1], p_sh2, mesh2)

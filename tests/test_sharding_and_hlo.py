@@ -63,7 +63,7 @@ def test_sharded_train_step_runs_on_local_mesh():
     mesh = make_local_mesh()
     cfg = get_arch("stablelm-1.6b").reduced()
     model = get_model(cfg)
-    with mesh:
+    with jax.set_mesh(mesh):
         params = model.init(KEY, cfg)
         p_sh = param_shardings(params, mesh)
         params = jax.device_put(params, p_sh)
