@@ -1,0 +1,8 @@
+"""CPU tests of the chip benchmark's yardstick. Run from the repository
+root with ``python -m pytest chipbench/tests``."""
+import sys
+from pathlib import Path
+
+BENCH_DIR = Path(__file__).resolve().parents[1]
+sys.path.insert(0, str(BENCH_DIR.parent / "src"))
+sys.path.insert(0, str(BENCH_DIR))
