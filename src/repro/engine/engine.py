@@ -50,6 +50,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.models import get_model
+from repro.obs.tracer import Span
 
 from .faults import DegradationLadder, FaultInjector, StepFailure
 from .kvcache import clear_slot, init_slot_cache, rollback_slot, \
@@ -107,8 +108,9 @@ def _jitted_entry_points(cfg, fused: bool, greedy: bool):
         logits, cache = transformer.decode_step_slots(p, cfg, c, t, pos,
                                                       fused=fused)
         if greedy:
-            return jnp.argmax(logits[:, -1], axis=-1).astype(jnp.int32), \
-                cache
+            with jax.named_scope("lm_head"):
+                toks = jnp.argmax(logits[:, -1], axis=-1).astype(jnp.int32)
+            return toks, cache
         return logits, cache
 
     decode = jax.jit(step, donate_argnums=(1,))
@@ -216,14 +218,13 @@ class EngineConfig:
                                         # coarse in production)
     trace: bool = False                 # default-OFF observability
                                         # (repro.obs, DESIGN.md §10):
-                                        # lifecycle events + per-step
-                                        # phase spans with dispatch-vs-
-                                        # device-wait attribution. Traced
-                                        # mode inserts block_until_ready
-                                        # sync points to attribute async
-                                        # dispatch — it is a PROFILING
-                                        # mode, not free; disabled, every
-                                        # site pays one branch
+                                        # lifecycle events + a ring-
+                                        # buffer record of every phase
+                                        # span with dispatch-vs-device-
+                                        # wait attribution. The spans'
+                                        # profiler annotations are
+                                        # written either way; no sync
+                                        # point is added
     trace_capacity: int = 1 << 16       # tracer ring-buffer records;
                                         # oldest drop first on overflow
     trace_kv_every: int = 0             # >0: sample KV quantization-
@@ -351,8 +352,9 @@ class Engine:
         # --- observability (repro.obs, DESIGN.md §10) -------------------
         # an explicit tracer wins; else ecfg.trace mints one on the
         # engine's own clock (trace time and metrics share one axis).
-        # Falsy tracers normalize to None so every hot-path site guards
-        # with a single `if tr:` branch — the whole disabled-mode cost.
+        # Falsy tracers normalize to None so every record site guards
+        # with a single `if tr:` branch; phase spans (self._span) write
+        # their profiler annotation either way.
         if tracer is None and ecfg.trace:
             from repro.obs import Tracer
             tracer = Tracer(capacity=ecfg.trace_capacity, clock=clock,
@@ -569,6 +571,10 @@ class Engine:
         # whose prefill work ran while OTHER requests were decoding —
         # prefill with an idle decode batch stalls nobody)
         self.step_s: list[float] = []
+        # seconds this step's readbacks (decode tokens, first tokens)
+        # waited on the device (the flight record's wait_s: a slow step
+        # waited on the device or on the host)
+        self._step_wait_s = 0.0
         self.step_prefill_tokens: list[int] = []
         self.step_decode_slots: list[int] = []
         self._t_start: Optional[float] = None
@@ -725,11 +731,16 @@ class Engine:
         self._fail_streak[slot] = 0
 
     def _start_decoding(self, slot: int, req: EngineRequest, logits_row,
-                        S: int):
+                        S: int, phase: str):
         """Shared admission tail: sample the FIRST generated token from the
         prompt's final logits row and move the slot into decode (or retire
-        it on eos / exhausted budget)."""
-        first = int(self._sample(logits_row))
+        it on eos / exhausted budget). Reading the token waits on the
+        device for the prefill: that is the child span
+        ``<phase>.readback``, counted in the step's wait like a decode
+        readback."""
+        with self._span(phase + ".readback") as readback:
+            first = int(self._sample(logits_row))
+        self._step_wait_s += readback.dur
         req.t_first_token = self.clock()
         if self.tracer:
             self.tracer.event("first_token", uid=req.uid, slot=slot)
@@ -757,31 +768,30 @@ class Engine:
             req.t_first_token = req.t_submit
             self.sched.retire(slot, reason="zero_budget")
             return 0
-        tr = self.tracer
-        t_span = tr.begin() if tr else 0.0
         S = len(req.prompt)
-        Sp = self._bucket(S)
-        toks = np.zeros((1, Sp), np.int32)
-        toks[0, :S] = req.prompt                      # right-pad
-        t_d = tr.now() if tr else 0.0
-        logits, pcache = self._prefill(self.params, jnp.asarray(toks))
-        dispatch_s = (tr.now() - t_d) if tr else 0.0
-        self.n_prefills += 1
-        FP_PREFILL_MATERIALIZATIONS += 1
-        # only [0, S) becomes visible; bucket padding stays masked forever
-        self.cache = self._write(self.cache, jnp.int32(slot), pcache,
-                                 jnp.int32(S))
-        if self._spec is not None:
-            # mirror the prompt into the draft cache (its own one-shot
-            # dense materialization — count it honestly)
-            self._spec.prefill_oneshot(jnp.asarray(toks), slot, S)
+        with self._span("prefill_oneshot", slot=slot) as sp:
+            Sp = self._bucket(S)
+            toks = np.zeros((1, Sp), np.int32)
+            toks[0, :S] = req.prompt                  # right-pad
+            t_d = self.clock()
+            logits, pcache = self._prefill(self.params, jnp.asarray(toks))
+            dispatch_s = self.clock() - t_d
+            self.n_prefills += 1
             FP_PREFILL_MATERIALIZATIONS += 1
-        # _start_decoding's sample blocks on the prefill logits, so the
-        # span's tail (dur - dispatch_s) is device wait + first-token work
-        self._start_decoding(slot, req, logits[0, S - 1], S)
-        if tr:
-            tr.span_end("prefill_oneshot", t_span, slot=slot, uid=req.uid,
-                        tokens=S, dispatch_s=dispatch_s)
+            # only [0, S) becomes visible; bucket padding stays masked
+            self.cache = self._write(self.cache, jnp.int32(slot), pcache,
+                                     jnp.int32(S))
+            if self._spec is not None:
+                # mirror the prompt into the draft cache (its own one-shot
+                # dense materialization — count it honestly)
+                self._spec.prefill_oneshot(jnp.asarray(toks), slot, S)
+                FP_PREFILL_MATERIALIZATIONS += 1
+            # _start_decoding's sample blocks on the prefill logits, so
+            # the span's tail (dur - dispatch_s) is device wait +
+            # first-token work
+            self._start_decoding(slot, req, logits[0, S - 1], S,
+                                 "prefill_oneshot")
+            sp.note(uid=req.uid, tokens=S, dispatch_s=dispatch_s)
         return S
 
     # --------------------------------------------------- chunked prefill --
@@ -816,10 +826,13 @@ class Engine:
         under contention (an int8 cache makes boundary placement visible:
         tokens after a boundary attend the QUANTIZED prefix, so
         load-dependent boundaries would make generations irreproducible).
+        Dispatch is asynchronous and nothing here waits on the chunk
+        (until a completed prompt samples its first token): a chunk's
+        device time is read from a profiler trace, where its
+        ``repro.prefill_chunk`` span and its device ops share one clock.
         Returns prompt tokens processed."""
         budget = self.ecfg.prefill_chunk
         spent = 0
-        tr = self.tracer
         for slot in self.sched.prefill_slots():
             req = self.sched.slots[slot]
             S = len(req.prompt)
@@ -827,42 +840,32 @@ class Engine:
             n = min(self.ecfg.prefill_chunk, S - done)
             if n > budget:          # whole chunk or nothing (FCFS head
                 break               # waits; boundaries stay load-free)
-            t_span = tr.begin() if tr else 0.0
-            pos_start = done
-            Sc = bucket_len(n, self.ecfg.prefill_bucket,
-                            self.ecfg.prefill_chunk)
-            toks = np.zeros((1, Sc), np.int32)
-            toks[0, :n] = req.prompt[done:done + n]   # right-pad the chunk
-            t_d = tr.now() if tr else 0.0
-            logits, self.cache = self._chunk_prefill(
-                self.params, self.cache, jnp.asarray(toks), jnp.int32(slot),
-                jnp.int32(done), jnp.int32(n))
-            dispatch_s = (tr.now() - t_d) if tr else 0.0
-            if self._spec is not None:     # mirror the chunk to the draft
-                self._spec.prefill_chunk(jnp.asarray(toks), slot, done, n)
-            wait_s = 0.0
-            if tr:
-                # traced-mode sync: dispatch is async, so without this
-                # the chunk's device time would surface as somebody
-                # else's wait. A deliberate profiling cost.
-                t_w = tr.now()
-                jax.block_until_ready(logits)
-                wait_s = tr.now() - t_w
-            self.n_prefill_chunks += 1
-            if self._mx:
-                self._mx["prefill_chunks"].inc()
-            budget -= n
-            spent += n
-            done += n
-            self._prefill_prog[slot] = done
-            self._pos[slot] = done                    # parked position
-            if done >= S:                             # prompt complete
-                self.sched.finish_prefill(slot)
-                self._start_decoding(slot, req, logits[0], S)
-            if tr:
-                tr.span_end("prefill_chunk", t_span, slot=slot,
-                            uid=req.uid, pos_start=pos_start, n=n,
-                            dispatch_s=dispatch_s, wait_s=wait_s)
+            with self._span("prefill_chunk", slot=slot, pos_start=done,
+                            n=n) as sp:
+                Sc = bucket_len(n, self.ecfg.prefill_bucket,
+                                self.ecfg.prefill_chunk)
+                toks = np.zeros((1, Sc), np.int32)
+                toks[0, :n] = req.prompt[done:done + n]   # right-pad
+                t_d = self.clock()
+                logits, self.cache = self._chunk_prefill(
+                    self.params, self.cache, jnp.asarray(toks),
+                    jnp.int32(slot), jnp.int32(done), jnp.int32(n))
+                sp.note(uid=req.uid, dispatch_s=self.clock() - t_d)
+                if self._spec is not None:  # mirror the chunk to the draft
+                    self._spec.prefill_chunk(jnp.asarray(toks), slot, done,
+                                             n)
+                self.n_prefill_chunks += 1
+                if self._mx:
+                    self._mx["prefill_chunks"].inc()
+                budget -= n
+                spent += n
+                done += n
+                self._prefill_prog[slot] = done
+                self._pos[slot] = done                # parked position
+                if done >= S:                         # prompt complete
+                    self.sched.finish_prefill(slot)
+                    self._start_decoding(slot, req, logits[0], S,
+                                         "prefill_chunk")
         return spent
 
     # ------------------------------------------- speculative decoding --
@@ -902,58 +905,53 @@ class Engine:
         for s in active:
             req = self.sched.slots[s]
             ws = int(w[s])
-            t_span = tr.begin() if tr else 0.0
-            toks = np.zeros((1, Sq), np.int32)
-            toks[0, 0] = self._last_tok[s]
-            toks[0, 1:ws] = drafts[:ws - 1, s]
-            t_d = tr.now() if tr else 0.0
-            garg, self.cache = self._verify(
-                self.params, self.cache, jnp.asarray(toks), jnp.int32(s),
-                jnp.int32(pos0[s]), jnp.int32(ws))
-            t_w = tr.now() if tr else 0.0
-            garg = np.asarray(garg)            # (Sq,) target argmax rows
+            with self._span("verify", slot=s) as sp:
+                toks = np.zeros((1, Sq), np.int32)
+                toks[0, 0] = self._last_tok[s]
+                toks[0, 1:ws] = drafts[:ws - 1, s]
+                t_d = self.clock()
+                garg, self.cache = self._verify(
+                    self.params, self.cache, jnp.asarray(toks),
+                    jnp.int32(s), jnp.int32(pos0[s]), jnp.int32(ws))
+                t_w = self.clock()
+                garg = np.asarray(garg)        # (Sq,) target argmax rows
                                                # — the device wait
-            wait_s = (tr.now() - t_w) if tr else 0.0
-            self.n_verify_calls += 1
-            self.n_verify_tokens += ws
-            a = accept_length(drafts[:, s], garg, ws)
-            self.sched.note_spec(s, proposed=ws - 1, accepted=a)
-            if tr:
-                tr.span_end("verify", t_span, slot=s, uid=req.uid,
-                            tokens=ws, accepted=a,
-                            dispatch_s=t_w - t_d, wait_s=wait_s)
+                wait_s = self.clock() - t_w
+                self.n_verify_calls += 1
+                self.n_verify_tokens += ws
+                a = accept_length(drafts[:, s], garg, ws)
+                self.sched.note_spec(s, proposed=ws - 1, accepted=a)
+                sp.note(uid=req.uid, tokens=ws, accepted=a,
+                        dispatch_s=t_w - t_d, wait_s=wait_s)
             new_pos = int(pos0[s]) + a + 1
             if a + 1 < ws:                     # rejected rows to undo
-                t_rb = tr.begin() if tr else 0.0
-                self.cache = _ROLLBACK(self.cache, jnp.int32(s),
-                                       jnp.int32(new_pos))
-                self._spec.rollback(s, new_pos)
+                with self._span("rollback", slot=s) as sp:
+                    self.cache = _ROLLBACK(self.cache, jnp.int32(s),
+                                           jnp.int32(new_pos))
+                    self._spec.rollback(s, new_pos)
+                    sp.note(uid=req.uid, accept_len=new_pos)
                 if tr:
-                    tr.span_end("rollback", t_rb, slot=s, uid=req.uid,
-                                accept_len=new_pos)
                     tr.event("rollback", uid=req.uid, slot=s,
                              accept_len=new_pos,
                              rejected=ws - (a + 1))
             # commit g_1..g_{a+1} with the same eos/budget/max_len
             # semantics as sequential decode steps
-            t_c = tr.begin() if tr else 0.0
-            for t in (int(x) for x in garg[:a + 1]):
-                if t == self.ecfg.eos_id:      # eos is never emitted
-                    self._retire(s, "eos")
-                    break
-                req.out.append(t)
-                self.n_spec_commit_tokens += 1
-                self._last_tok[s] = t
-                self._pos[s] += 1
-                if len(req.out) >= req.max_new_tokens:
-                    self._retire(s, "budget")
-                    break
-                if self._pos[s] >= self.ecfg.max_len:
-                    self._retire(s, "max_len")
-                    break
-            if tr:
-                tr.span_end("accept_commit", t_c, slot=s, uid=req.uid,
-                            committed=a + 1)
+            with self._span("accept_commit", slot=s) as sp:
+                sp.note(uid=req.uid, committed=a + 1)
+                for t in (int(x) for x in garg[:a + 1]):
+                    if t == self.ecfg.eos_id:  # eos is never emitted
+                        self._retire(s, "eos")
+                        break
+                    req.out.append(t)
+                    self.n_spec_commit_tokens += 1
+                    self._last_tok[s] = t
+                    self._pos[s] += 1
+                    if len(req.out) >= req.max_new_tokens:
+                        self._retire(s, "budget")
+                        break
+                    if self._pos[s] >= self.ecfg.max_len:
+                        self._retire(s, "max_len")
+                        break
         self.n_spec_steps += 1
         self.spec_step_s.append(self.clock() - t0)
         self.sched.note_step(len(active))
@@ -966,38 +964,38 @@ class Engine:
     # --------------------------------------- plain decode with retry --
     def _dispatch_decode(self, n_active: int) -> np.ndarray:
         """One batched plain-decode dispatch over all N slots; returns
-        the per-slot sampled tokens on host. The decode SPAN opens before
-        staging: the two host->device puts are real per-step decode cost
-        (on small models they rival the matmuls) and must attribute to
-        the phase, not leak into the step span's uncovered remainder. The
-        tracked decode_step_s metric keeps its historical bracket
-        (post-staging t0) so its trend stays comparable across PRs."""
-        tr = self.tracer
-        t_span = tr.begin() if tr else 0.0
-        tokens = jnp.asarray(self._last_tok[:, None])
-        pos = jnp.asarray(self._pos)
-        t0 = self.clock()
-        if self._greedy:
-            toks, self.cache = self._decode(self.params, self.cache,
-                                            tokens, pos)
-            t_w = tr.now() if tr else 0.0
-            toks = np.asarray(toks)
-        else:
-            logits, self.cache = self._decode(self.params, self.cache,
-                                              tokens, pos)
-            t_w = tr.now() if tr else 0.0
-            toks = np.asarray(self._sample(logits[:, -1]))
-        self.n_decode_steps += 1
-        # toks is on host here, so this brackets the real per-step
-        # decode latency (dispatch + device compute + sample)
-        dt = self.clock() - t0
-        self.decode_step_s.append(dt)
-        if self._mx:
-            self._mx["decode_steps"].inc()
-            self._mx["decode_s"].observe(dt)
-        if tr:
-            tr.span_end("decode", t_span, slots=n_active,
-                        dispatch_s=t_w - t0, wait_s=tr.now() - t_w)
+        the per-slot sampled tokens on host. The ``decode`` span opens
+        before staging, and its children split it: ``decode.stage`` (the
+        two host->device puts, on small models as costly as the
+        matmuls), ``decode.dispatch`` (the jitted call until it returns)
+        and ``decode.readback`` (the host transfer, which waits on the
+        device). Their durations are the span's ``dispatch_s`` and
+        ``wait_s``, and the readback's counts in the flight record's
+        ``wait_s``. The tracked decode_step_s metric keeps its
+        historical bracket (post-staging t0) so its trend stays
+        comparable across PRs."""
+        with self._span("decode", slots=n_active) as sp:
+            with self._span("decode.stage"):
+                tokens = jnp.asarray(self._last_tok[:, None])
+                pos = jnp.asarray(self._pos)
+            t0 = self.clock()
+            with self._span("decode.dispatch") as dispatch:
+                out, self.cache = self._decode(self.params, self.cache,
+                                               tokens, pos)
+                if not self._greedy:
+                    out = self._sample(out[:, -1])
+            with self._span("decode.readback") as readback:
+                toks = np.asarray(out)
+            self.n_decode_steps += 1
+            # toks is on host here, so this brackets the real per-step
+            # decode latency (dispatch + device compute + sample)
+            dt = self.clock() - t0
+            self.decode_step_s.append(dt)
+            if self._mx:
+                self._mx["decode_steps"].inc()
+                self._mx["decode_s"].observe(dt)
+            sp.note(dispatch_s=dispatch.dur, wait_s=readback.dur)
+        self._step_wait_s += readback.dur
         return toks
 
     def _decode_with_retry(self, active: list) \
@@ -1122,12 +1120,22 @@ class Engine:
             backlog += -(-rem // self.ecfg.prefill_chunk)
         return backlog
 
+    def _span(self, name: str, **args) -> Span:
+        """A phase span on the engine's clock (``repro.<name>`` in a
+        profiler trace; a ring-buffer record too when tracing)."""
+        return Span(self.tracer, name, self.clock, **args)
+
     def step(self) -> list[EngineRequest]:
         """Admit + (chunk-budgeted) prefill + one batched decode step.
         Returns requests finishing now."""
+        with self._span("step") as sp:
+            return self._step(sp)
+
+    def _step(self, sp: Span) -> list[EngineRequest]:
         if self._t_start is None:
             self._t_start = self.clock()
         t_step0 = self.clock()
+        self._step_wait_s = 0.0
         # --- injected process death (faults.crash_rate, §13) -----------
         # drawn before ANY step work: the journal's durability horizon is
         # the step boundary, so flush whatever arrived since the last
@@ -1148,38 +1156,13 @@ class Engine:
         # this step holds the step's decode/verify dispatch wall (the
         # coarse dispatch split in the flight record)
         n_dec0, n_spec0 = len(self.decode_step_s), len(self.spec_step_s)
-        if self._any_deadlines:
-            self._enforce_deadlines()
-        # --- degradation ladder (faults.DegradationLadder, §12) --------
-        # pressure = queue depth + prefill backlog chunks, fed BEFORE
-        # admission so this step's policy reflects the load it is about
-        # to admit under
-        defer = ()
-        if self._ladder is not None:
-            pressure = len(self.sched.queue) + self._prefill_backlog()
-            rung = self._ladder.update(pressure)
-            if rung != self._rung:
-                if self._mx:
-                    self._mx["degr_transitions"].inc()
-                if self.tracer:
-                    self.tracer.event("degrade", rung=rung,
-                                      prev=self._rung, pressure=pressure)
-                self._rung = rung
-            if self._mx:
-                self._mx["rung"].set(rung)
-            if rung >= 3:
-                # shed queued load (batch class first) back down to the
-                # rung-2 threshold — enough relief to stop climbing
-                self.sched.shed_queued_to(int(self._ladder.thresholds[1]))
-            if rung >= 2:
-                defer = ("batch",)
+        with self._span("admit"):
+            placed = self._admit()
         prefill_tokens = 0
-        for slot, req in self.sched.admit(defer=defer):
-            if self.ecfg.prefill_chunk:
-                self._admit_chunked(slot, req)
-            else:
+        if not self.ecfg.prefill_chunk:
+            for slot, req in placed:
                 prefill_tokens += self._admit_one(slot, req)
-        if self.ecfg.prefill_chunk:
+        else:
             prefill_tokens = self._prefill_work()
             # nobody is decoding ⇒ nobody can be stalled: keep spending
             # whole-chunk budgets until a slot finishes its prompt and
@@ -1213,40 +1196,84 @@ class Engine:
                 # so suspension is the free first degradation
                 self._spec.note_suspended()
             toks, active = self._decode_with_retry(active)
-            tr = self.tracer
-            t_c = tr.begin() if tr else 0.0
-            emitted = 0
-            for slot in active:
-                req = self.sched.slots[slot]
-                t = int(toks[slot])
-                self._pos[slot] += 1
-                if t == self.ecfg.eos_id:
-                    self._retire(slot, "eos")
-                    continue
-                req.out.append(t)
-                emitted += 1
-                self._last_tok[slot] = t
-                if len(req.out) >= req.max_new_tokens:
-                    self._retire(slot, "budget")
-                elif self._pos[slot] >= self.ecfg.max_len:
-                    self._retire(slot, "max_len")
-            self.sched.note_step(len(active))
-            if self._mx:
-                self._mx["tokens"].inc(emitted)
-            if tr:
-                tr.span_end("accept_commit", t_c, slots=len(active))
+            with self._span("accept_commit") as sc:
+                sc.note(slots=len(active))
+                emitted = 0
+                for slot in active:
+                    req = self.sched.slots[slot]
+                    t = int(toks[slot])
+                    self._pos[slot] += 1
+                    if t == self.ecfg.eos_id:
+                        self._retire(slot, "eos")
+                        continue
+                    req.out.append(t)
+                    emitted += 1
+                    self._last_tok[slot] = t
+                    if len(req.out) >= req.max_new_tokens:
+                        self._retire(slot, "budget")
+                    elif self._pos[slot] >= self.ecfg.max_len:
+                        self._retire(slot, "max_len")
+                self.sched.note_step(len(active))
+                if self._mx:
+                    self._mx["tokens"].inc(emitted)
         tr = self.tracer
         if tr and self.ecfg.trace_kv_every and self.cache.mode == "int8" \
                 and len(self.step_s) % self.ecfg.trace_kv_every == 0:
             # periodic KV quantization-quality sample: a host transfer of
             # live cache rows — traced-mode-only cost, span-attributed
             from .kvcache import kv_quality_counters
-            t_q = tr.begin()
-            tr.counter("kv_quality", kv_quality_counters(self.cache))
-            tr.span_end("kv_sample", t_q)
+            with self._span("kv_sample"):
+                tr.counter("kv_quality", kv_quality_counters(self.cache))
         self.step_s.append(self.clock() - t_step0)
         self.step_prefill_tokens.append(prefill_tokens)
         self.step_decode_slots.append(n_decoding_before)
+        sp.note(prefill_tokens=prefill_tokens,
+                decode_slots=n_decoding_before)
+        with self._span("record"):
+            self._record_step(prefill_tokens, n_dec0, n_spec0,
+                              n_decoding_before)
+        return self.sched.finished[n_done_before:]
+
+    def _admit(self) -> list:
+        """Deadline sweep, degradation ladder and admission; returns the
+        (slot, request) pairs admitted. Chunked admission only marks a
+        slot mid-prefill, so it happens here; a one-shot prefill is the
+        caller's."""
+        if self._any_deadlines:
+            self._enforce_deadlines()
+        # --- degradation ladder (faults.DegradationLadder, §12) --------
+        # pressure = queue depth + prefill backlog chunks, fed BEFORE
+        # admission so this step's policy reflects the load it is about
+        # to admit under
+        defer = ()
+        if self._ladder is not None:
+            pressure = len(self.sched.queue) + self._prefill_backlog()
+            rung = self._ladder.update(pressure)
+            if rung != self._rung:
+                if self._mx:
+                    self._mx["degr_transitions"].inc()
+                if self.tracer:
+                    self.tracer.event("degrade", rung=rung,
+                                      prev=self._rung, pressure=pressure)
+                self._rung = rung
+            if self._mx:
+                self._mx["rung"].set(rung)
+            if rung >= 3:
+                # shed queued load (batch class first) back down to the
+                # rung-2 threshold — enough relief to stop climbing
+                self.sched.shed_queued_to(int(self._ladder.thresholds[1]))
+            if rung >= 2:
+                defer = ("batch",)
+        placed = self.sched.admit(defer=defer)
+        if self.ecfg.prefill_chunk:
+            for slot, req in placed:
+                self._admit_chunked(slot, req)
+        return placed
+
+    def _record_step(self, prefill_tokens: int, n_dec0: int, n_spec0: int,
+                     n_decoding_before: int) -> None:
+        """End-of-step bookkeeping: registry gauges, journal sync,
+        periodic snapshot, flight record and detector sweep."""
         mx = self._mx
         if mx:
             # end-of-step queueing gauges: O(n_slots) host bookkeeping,
@@ -1293,10 +1320,6 @@ class Engine:
                         spans.append(sum(hist[5:]) / sum(hist))
                 if spans:
                     self._last_span_frac = max(spans)
-        if tr:
-            tr.span_end("step", t_step0,
-                        prefill_tokens=prefill_tokens,
-                        decode_slots=n_decoding_before)
         # --- crash safety (§13): make the boundary durable --------------
         # journal fsync FIRST, then the periodic snapshot — so a snapshot
         # never holds state the journal hasn't seen (snapshot ⊆ WAL)
@@ -1322,6 +1345,7 @@ class Engine:
                      if len(self.spec_step_s) > n_spec0 else 0.0), 6),
                 "draft_s": round(self._spec.last_draft_s, 6)
                 if self._spec is not None and self._rung < 1 else 0.0,
+                "wait_s": round(self._step_wait_s, 6),
                 "queue": len(self.sched.queue),
                 "backlog": self._prefill_backlog(),
                 "occupied": len(uids),
@@ -1345,7 +1369,6 @@ class Engine:
                 firings = det.sweep(rec)
                 if firings:
                     self._capture_incident(firings)
-        return self.sched.finished[n_done_before:]
 
     # -------------------------------------------- incident capture (§14) --
     def _capture_incident(self, firings, force: bool = False):

@@ -212,8 +212,14 @@ def slot_layer_write(cl: SlotKVCache, k_new, v_new, positions
     cl: per-layer slice — leaves (N, T, Hkv, D) / (N, T, Hkv, C) / (N, T).
     k_new/v_new: (N, 1, Hkv, D) post-RoPE. positions: (N, 1) int32 absolute
     per-slot positions (the time-index written is positions % T, though the
-    engine never wraps — it retires at max_len).
+    engine never wraps — it retires at max_len). Runs under the name
+    ``kv_write`` (op_name metadata), as does the chunk write's scatter.
     """
+    with jax.named_scope("kv_write"):
+        return _slot_layer_write(cl, k_new, v_new, positions)
+
+
+def _slot_layer_write(cl, k_new, v_new, positions):
     T = cl.k.shape[-3]
     slot_t = (positions[:, 0] % T).astype(jnp.int32)       # (N,)
 
@@ -341,6 +347,13 @@ def slot_chunk_prefill(cl: SlotKVCache, q, k_new, v_new, slot, pos_start,
         qk, qv = k_new, v_new
         scale_upd = {}
 
+    with jax.named_scope("kv_write"):
+        return o, _scatter_chunk(cl, qk, qv, scale_upd, slot, pos_start,
+                                 length, Sq)
+
+
+def _scatter_chunk(cl, qk, qv, scale_upd, slot, pos_start, length, Sq):
+    """The chunk's codes (quantized in-kernel) into the slot's rows."""
     rows = pos_start + jnp.arange(Sq, dtype=jnp.int32)
     posv = jnp.where(jnp.arange(Sq) < length, rows, jnp.int32(-1))
 
@@ -349,11 +362,10 @@ def slot_chunk_prefill(cl: SlotKVCache, q, k_new, v_new, slot, pos_start,
         # past max_len — those rows carry no valid tokens by construction
         return buf.at[slot, rows].set(upd.astype(buf.dtype), mode="drop")
 
-    new_cl = dataclasses.replace(
+    return dataclasses.replace(
         cl, k=put(cl.k, qk), v=put(cl.v, qv),
         kv_pos=cl.kv_pos.at[slot, rows].set(posv, mode="drop"),
         **{f: put(buf, upd) for f, (buf, upd) in scale_upd.items()})
-    return o, new_cl
 
 
 def hotswap_static_scales(cache: SlotKVCache, kv_scales: dict

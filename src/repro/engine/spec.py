@@ -47,6 +47,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.obs.tracer import Span
+
 from . import engine as _engine
 from .kvcache import init_slot_cache
 
@@ -245,34 +247,30 @@ class SpecDecoder:
         cur_pos = np.asarray(pos, np.int32).copy()
         steps = np.asarray(steps)
         drafts = np.zeros((self.k, N), np.int32)
-        tr = self.tracer
         mx = self._mx
-        t_span = tr.begin() if tr else 0.0
         t_pass = time.perf_counter()
         dispatch_s = wait_s = 0.0
         n_iter = int(steps.max())
-        for j in range(n_iter):
-            if tr:
-                t_d = tr.now()
-            toks, self.cache = self._decode(
-                self.params, self.cache, jnp.asarray(cur_tok[:, None]),
-                jnp.asarray(cur_pos))
-            if tr:
-                dispatch_s += (t_w := tr.now()) - t_d
-            toks = np.asarray(toks)                # device wait per iter
-            if tr:
-                wait_s += tr.now() - t_w
-            self.n_draft_steps += 1
-            if j < self.k:
-                drafts[j] = toks
-            adv = (j + 1) < steps
-            cur_tok = np.where(adv, toks, cur_tok).astype(np.int32)
-            cur_pos = np.where(adv, cur_pos + 1, cur_pos).astype(np.int32)
+        with Span(self.tracer, "draft", iters=n_iter) as sp:
+            clock = sp.clock
+            for j in range(n_iter):
+                t_d = clock()
+                toks, self.cache = self._decode(
+                    self.params, self.cache, jnp.asarray(cur_tok[:, None]),
+                    jnp.asarray(cur_pos))
+                dispatch_s += (t_w := clock()) - t_d
+                toks = np.asarray(toks)            # device wait per iter
+                wait_s += clock() - t_w
+                self.n_draft_steps += 1
+                if j < self.k:
+                    drafts[j] = toks
+                adv = (j + 1) < steps
+                cur_tok = np.where(adv, toks, cur_tok).astype(np.int32)
+                cur_pos = np.where(adv, cur_pos + 1,
+                                   cur_pos).astype(np.int32)
+            sp.note(dispatch_s=dispatch_s, wait_s=wait_s)
         self.last_draft_s = time.perf_counter() - t_pass
         if mx:
             mx["steps"].inc(n_iter)
             mx["draft_s"].observe(self.last_draft_s)
-        if tr:
-            tr.span_end("draft", t_span, iters=n_iter,
-                        dispatch_s=dispatch_s, wait_s=wait_s)
         return drafts

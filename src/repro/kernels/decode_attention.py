@@ -205,6 +205,7 @@ def _decode_attention_pallas(q, k, v, kv_pos, q_pos, scales, *, mode,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
+        name="decode_attention",
     )(q_pos.astype(jnp.int32), *args)
     return out.reshape(N, Hq, D)
 

@@ -102,15 +102,21 @@ def linear(x: jnp.ndarray, w: Union[jnp.ndarray, SplitQuantTensor],
     NOTE (K-padding correctness): with use_pallas, padded K rows of the
     packed weight dequantize to  qmin·recip + shift ≠ 0, but the matching x
     columns are zero-padded so the extra products are exactly 0.
+
+    A SplitQuantTensor's whole product, the per-call packing included,
+    runs under the name ``dequant_matmul`` (op_name metadata only), so a
+    profiler trace gives its device time.
     """
     if isinstance(w, SplitQuantTensor):
-        if w.q.ndim != 2:
-            wx = w.dequantize()
-            y = jnp.dot(x, wx.astype(x.dtype))
-        else:
-            qp, cp, recip, shift = pack_for_kernel(w)
-            y = quantized_matmul(x, qp, cp, recip, shift, bits=w.bits, k=w.k,
-                                 use_pallas=use_pallas, interpret=interpret)
+        with jax.named_scope("dequant_matmul"):
+            if w.q.ndim != 2:
+                wx = w.dequantize()
+                y = jnp.dot(x, wx.astype(x.dtype))
+            else:
+                qp, cp, recip, shift = pack_for_kernel(w)
+                y = quantized_matmul(x, qp, cp, recip, shift, bits=w.bits,
+                                     k=w.k, use_pallas=use_pallas,
+                                     interpret=interpret)
     else:
         y = jnp.dot(x, w)
     if b is not None:

@@ -260,6 +260,7 @@ def _prefill_attention_pallas(q, k_new, v_new, cache_k, cache_v, kv_pos,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
+        name="prefill_attention",
     )(info, *args)
     return o.reshape(Hkv, Sq, G, D).transpose(1, 0, 2, 3).reshape(Sq, Hq, D)
 

@@ -4,6 +4,13 @@ Layers are stacked (leading L axis) and executed with ``jax.lax.scan`` +
 ``jax.checkpoint`` so HLO size and compile time are depth-independent (a
 126-layer llama3-405b compiles as one scanned block). Heterogeneous stacks
 (DeepSeek-style leading dense layers before MoE) are two scans.
+
+The parts of a forward pass carry names (``jax.named_scope``, which sets
+each op's ``op_name`` metadata and changes nothing else), so a profiler
+trace splits the device time by part: ``embed``; ``layers``, the scan,
+whose own slicing of each layer's weights and cache and stacking of the
+new cache lies outside ``layer``, the scan body; and ``lm_head``, the
+final norm and the head projection.
 """
 from __future__ import annotations
 
@@ -102,6 +109,15 @@ def _scan_stack(cfg, stacked, x, positions, cache, *, moe, kv_chunk,
                            spec_verify=spec_verify)
     if remat:
         fn = jax.checkpoint(fn, static_argnums=())
+    with jax.named_scope("layers"):
+        return _scan_layers(fn, stacked, x, positions, cache, want_kv)
+
+
+def _scan_layers(fn, stacked, x, positions, cache, want_kv):
+    """The scan itself; ``layer`` names its body."""
+    def fn_scoped(*a):
+        with jax.named_scope("layer"):
+            return fn(*a)
 
     if cache is not None and not isinstance(cache, KVCache):
         # engine slot cache: scan the dataclass itself — every data leaf
@@ -109,7 +125,7 @@ def _scan_stack(cfg, stacked, x, positions, cache, *, moe, kv_chunk,
         def step(carry, xs):
             x, aux = carry
             lp, cl = xs
-            x, new_cl, a = fn(lp, x, positions, cl)
+            x, new_cl, a = fn_scoped(lp, x, positions, cl)
             return (x, aux + a), new_cl
         (x, aux), new_cache = jax.lax.scan(step, (x, jnp.float32(0)),
                                            (stacked, cache))
@@ -119,7 +135,7 @@ def _scan_stack(cfg, stacked, x, positions, cache, *, moe, kv_chunk,
         def step(carry, xs):
             x, aux = carry
             lp, ck, cv, sp = xs
-            x, new_c, a = fn(lp, x, positions, (ck, cv, sp))
+            x, new_c, a = fn_scoped(lp, x, positions, (ck, cv, sp))
             return (x, aux + a), new_c
         (x, aux), ys = jax.lax.scan(step, (x, jnp.float32(0)),
                                     (stacked, cache.k, cache.v, cache.slot_pos))
@@ -128,7 +144,7 @@ def _scan_stack(cfg, stacked, x, positions, cache, *, moe, kv_chunk,
 
     def step(carry, lp):
         x, aux = carry
-        x, kv, a = fn(lp, x, positions, None)
+        x, kv, a = fn_scoped(lp, x, positions, None)
         return (x, aux + a), kv if want_kv else None
     (x, aux), ys = jax.lax.scan(step, (x, jnp.float32(0)), stacked)
     return x, ys, aux
@@ -166,12 +182,13 @@ def forward(params, cfg, batch, cache: Optional[KVCache] = None,
     window's own K/V through cache storage so each row scores like a plain
     decode step, and logits for EVERY window row are returned (the accept
     rule compares per-position argmax)."""
-    if cache is not None:
-        x = embed_lookup(params["embed"], batch["tokens"])     # (B, 1)
-    else:
-        x, positions = (embed_inputs(params, cfg, batch)
-                        if positions is None else
-                        (embed_lookup(params["embed"], batch["tokens"]), positions))
+    with jax.named_scope("embed"):
+        if cache is not None:
+            x = embed_lookup(params["embed"], batch["tokens"])  # (B, 1)
+        else:
+            x, positions = (
+                embed_inputs(params, cfg, batch) if positions is None else
+                (embed_lookup(params["embed"], batch["tokens"]), positions))
 
     kv_pos_override = None
     if pad_mask is not None and cache is None:
@@ -212,6 +229,21 @@ def forward(params, cfg, batch, cache: Optional[KVCache] = None,
         aux += a
         (caches if cache is not None else kvs).append(c)
 
+    with jax.named_scope("lm_head"):
+        logits = _lm_head(params, cfg, x, slot_chunk, spec_verify)
+
+    new_cache = None
+    if cache is not None:
+        new_cache = (caches[0] if len(caches) == 1 else
+                     jax.tree_util.tree_map(
+                         lambda *xs: jnp.concatenate(xs, 0), *caches))
+    elif want_cache:
+        new_cache = assemble_cache(cfg, kvs, positions, max_len=cache_len,
+                                   pad_mask=pad_mask)
+    return logits, new_cache, aux
+
+
+def _lm_head(params, cfg, x, slot_chunk, spec_verify):
     if slot_chunk is not None and not spec_verify:
         # chunk prefill consumes ONLY the last valid token's logits (the
         # first-generated-token sample on the prompt's final chunk) —
@@ -228,17 +260,7 @@ def forward(params, cfg, batch, cache: Optional[KVCache] = None,
         logits = jnp.dot(x, table.T.astype(x.dtype))
     else:
         logits = dense(x, head)
-    logits = shard_hint(logits.astype(jnp.float32), "dp", None, "tp")
-
-    new_cache = None
-    if cache is not None:
-        new_cache = (caches[0] if len(caches) == 1 else
-                     jax.tree_util.tree_map(
-                         lambda *xs: jnp.concatenate(xs, 0), *caches))
-    elif want_cache:
-        new_cache = assemble_cache(cfg, kvs, positions, max_len=cache_len,
-                                   pad_mask=pad_mask)
-    return logits, new_cache, aux
+    return shard_hint(logits.astype(jnp.float32), "dp", None, "tp")
 
 
 def assemble_cache(cfg, kvs, positions, max_len: Optional[int] = None,

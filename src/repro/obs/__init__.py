@@ -1,7 +1,8 @@
 """Default-off observability for the serving stack (DESIGN.md §10).
 
-`tracer.Tracer` records spans/events/counters into a bounded ring buffer
-and exports JSONL + Chrome ``trace.json``; `schema` is the phase/
+`tracer.Span` puts each engine phase on the JAX profiler's clock (always)
+and `tracer.Tracer` records spans/events/counters into a bounded ring
+buffer and exports JSONL + Chrome ``trace.json``; `schema` is the phase/
 lifecycle vocabulary and validator; `report` aggregates traces into the
 phase-breakdown / waterfall views; `summary` is the shared
 percentile-with-empty-guard math every metrics consumer reuses;
@@ -28,11 +29,12 @@ from repro.obs.report import (lifecycle_summary, phase_breakdown,
 from repro.obs.schema import LIFECYCLE, PHASES, RETIRE_REASONS, \
     validate_events
 from repro.obs.summary import mean, pct, summarize, token_agreement
-from repro.obs.tracer import SCHEMA_VERSION, Tracer, chrome_trace, \
-    load_jsonl
+from repro.obs.tracer import ANNOTATION_PREFIX, SCHEMA_VERSION, Span, \
+    Tracer, chrome_trace, load_jsonl
 
 __all__ = [
-    "Tracer", "SCHEMA_VERSION", "chrome_trace", "load_jsonl",
+    "Tracer", "Span", "ANNOTATION_PREFIX", "SCHEMA_VERSION",
+    "chrome_trace", "load_jsonl",
     "PHASES", "LIFECYCLE", "RETIRE_REASONS", "validate_events",
     "phase_breakdown", "request_waterfalls", "lifecycle_summary",
     "pct", "mean", "summarize", "token_agreement",

@@ -14,6 +14,9 @@ Catalog (name → signal → default threshold):
                       warmup_steps baseline samples. The EWMA is fed
                       from the start, so jit-compile spikes during
                       warmup inflate the baseline instead of firing.
+                      The reason splits the step's wall into the
+                      decode readback's device wait (the record's
+                      wait_s) and host time.
   accept_collapse     scheduler acceptance EWMA drops below
                       accept_floor after having been >= 2×floor —
                       speculation is burning draft passes for nothing.
@@ -134,10 +137,14 @@ class AnomalyDetector:
             if (self._lat_n >= self.warmup_steps
                     and self._lat_ewma is not None and self._lat_ewma > 0
                     and step_s > self.latency_factor * self._lat_ewma):
+                wait = rec.get("wait_s")
                 raw.append(Firing(
                     "step_latency_spike", step,
                     f"step wall {step_s:.4f}s > {self.latency_factor:g}x "
-                    f"rolling baseline {self._lat_ewma:.4f}s",
+                    f"rolling baseline {self._lat_ewma:.4f}s"
+                    + ("" if wait is None else
+                       f"; {wait:.4f}s of it waiting on the device, "
+                       f"{step_s - wait:.4f}s on the host"),
                     value=float(step_s)))
             a = self.baseline_alpha
             self._lat_ewma = (float(step_s) if self._lat_ewma is None

@@ -17,6 +17,10 @@ One record per engine step, one flat dict per record:
               dispatch+device time; host-side work is step_s - decode_s;
               the fine dispatch/wait split needs --trace)
   draft_s     wall of the draft pass (spec mode; 0.0 otherwise)
+  wait_s      seconds the step's readbacks waited on the device (its
+              ``repro.*.readback`` spans, same clock reads: the decode's
+              tokens and a completed prompt's first token): a slow step
+              with a small wait_s was held on the host
   queue       admission queue depth at end of step
   backlog     queued prefill tokens (admission set-point signal)
   occupied    slots holding a request

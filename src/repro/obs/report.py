@@ -17,13 +17,16 @@ def phase_breakdown(records) -> dict:
     """Aggregate span records into the per-phase timeline summary.
 
     ``step`` spans are the denominator (total measured wall-clock step
-    time); every other phase nests inside a step, and the phases are
-    non-overlapping by construction (engine instrumentation brackets
-    disjoint regions), so ``coverage`` = attributed / step-total is the
-    fraction of step wall the taxonomy explains — the acceptance bar is
-    ≥ 0.9. Per phase: total/count/mean plus the ``dispatch_s`` (host
-    time inside the jit call) and ``wait_s`` (device wait) attribution,
-    with ``host_s = total − device wait`` (host incl. dispatch).
+    time); every other phase nests inside a step, and the top-level
+    phases are non-overlapping by construction (engine instrumentation
+    brackets disjoint regions), so ``coverage`` = attributed / step-total
+    is the fraction of step wall the taxonomy explains — the acceptance
+    bar is ≥ 0.9. Child phases (a dotted name such as ``decode.readback``)
+    are listed but neither attributed nor summed: their parent already
+    holds their time. Per phase: total/count/mean plus the ``dispatch_s``
+    (host time inside the jit call) and ``wait_s`` (device wait)
+    attribution, with ``host_s = total − device wait`` (host incl.
+    dispatch).
     """
     per: dict[str, dict] = {}
     step_total, step_count = 0.0, 0
@@ -39,15 +42,15 @@ def phase_breakdown(records) -> dict:
         d["count"] += 1
         d["dispatch_s"] += r.get("dispatch_s", 0.0)
         d["device_wait_s"] += r.get("wait_s", 0.0)
-    attributed = 0.0
     for d in per.values():
         d["mean_s"] = d["total_s"] / d["count"]
         d["host_s"] = d["total_s"] - d["device_wait_s"]
         d["frac_of_step"] = (d["total_s"] / step_total if step_total
                              else None)
-        attributed += d["total_s"]
-    dispatch = sum(d["dispatch_s"] for d in per.values())
-    wait = sum(d["device_wait_s"] for d in per.values())
+    top = [d for name, d in per.items() if "." not in name]
+    attributed = sum(d["total_s"] for d in top)
+    dispatch = sum(d["dispatch_s"] for d in top)
+    wait = sum(d["device_wait_s"] for d in top)
     return {
         "phases": per,
         "steps": step_count,

@@ -2,13 +2,19 @@
 dependency-free validator (CI's trace smoke runs it against the JSONL a
 traced serve run emits — see DESIGN.md §10 for the prose contract).
 
-Phase taxonomy (``span`` names) — each engine step tiles into these:
+Phase taxonomy (``span`` names; each is also the profiler annotation
+``repro.<name>``) — each engine step tiles into these:
 
 * ``step``            — the whole `Engine.step()` (the coverage
                         denominator; every other phase nests inside it)
-* ``prefill_oneshot`` — legacy dense per-request prefill + slot write
+* ``admit``           — deadline sweep, degradation ladder and admission
+                        of queued requests into free slots
+* ``prefill_oneshot`` — legacy dense per-request prefill + slot write;
+                        its child ``prefill_oneshot.readback`` reads the
+                        first token (a wait on the device)
 * ``prefill_chunk``   — one fused chunked-prefill dispatch (slot, uid,
-                        pos_start, n)
+                        pos_start, n); on a prompt's last chunk its child
+                        ``prefill_chunk.readback`` reads the first token
 * ``draft``           — the speculative draft pass over all slots
                         (aggregated per-iteration dispatch/wait fields)
 * ``verify``          — ONE slot's fused verify dispatch + device wait +
@@ -17,9 +23,18 @@ Phase taxonomy (``span`` names) — each engine step tiles into these:
 * ``accept_commit``   — host-side token commit loop (spec and plain
                         decode share the name; eos/budget retire runs
                         inside it)
-* ``decode``          — one batched plain decode dispatch + device wait
+* ``decode``          — one batched plain decode: its children
+                        ``decode.stage`` (the two host-to-device puts),
+                        ``decode.dispatch`` (the jitted call until it
+                        returns) and ``decode.readback`` (the host
+                        transfer that waits on the device)
 * ``kv_sample``       — the periodic KV quality-counter sample (its
                         cache→host transfer is traced-mode-only cost)
+* ``record``          — end-of-step bookkeeping: registry gauges, journal
+                        sync, snapshot, flight record, detector sweep
+
+A name with a dot (``decode.stage``) is a child phase: it nests inside
+the phase its name starts with and is left out of step coverage.
 
 Lifecycle vocabulary (``event`` names): ``submit``, ``admit``,
 ``first_token``, ``retire`` (with ``reason``), ``rollback``,
@@ -42,8 +57,10 @@ core invariant, tests/test_faults.py).
 """
 from __future__ import annotations
 
-PHASES = ("step", "prefill_oneshot", "prefill_chunk", "draft", "verify",
-          "rollback", "accept_commit", "decode", "kv_sample")
+PHASES = ("step", "admit", "prefill_oneshot", "prefill_oneshot.readback",
+          "prefill_chunk", "prefill_chunk.readback", "draft", "verify",
+          "rollback", "accept_commit", "decode", "decode.stage",
+          "decode.dispatch", "decode.readback", "kv_sample", "record")
 
 LIFECYCLE = ("submit", "admit", "first_token", "retire", "rollback",
              "cancel", "degrade", "snapshot", "restore")

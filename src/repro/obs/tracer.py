@@ -1,15 +1,19 @@
 """Monotonic-clock tracer with a bounded ring buffer (DESIGN.md §10).
 
-The serving stack is instrumented with three record kinds:
+Every engine phase is a `Span`: it always opens a JAX profiler
+annotation ``repro.<phase>`` (so a profiler trace shows the host's
+phases on the same clock as the device's ops), and with a tracer it also
+becomes a ring-buffer record. The serving stack is instrumented with
+three record kinds:
 
 * ``span``    — a timed phase (``name`` ∈ `schema.PHASES`) with ``ts``
   (seconds since the tracer epoch), ``dur``, and optional attribution
   fields: ``dispatch_s`` (host time until the jitted call returned —
   dispatch is asynchronous on every jax backend) and ``wait_s`` (the
-  ``block_until_ready``/host-transfer wait for the device result).
-  ``dur - wait_s`` is therefore host time, of which ``dispatch_s`` is
-  the jit-call share — the split that decides "dispatch-bound or
-  compute-bound" per phase.
+  host-transfer wait for the device result). ``dur - wait_s`` is
+  therefore host time, of which ``dispatch_s`` is the jit-call share.
+  Which device work a wait covers is read from a profiler trace, where
+  the device's ops and these spans share one clock.
 * ``event``   — an instantaneous per-request lifecycle point
   (``name`` ∈ `schema.LIFECYCLE`: submit → admit → first_token →
   retire, plus rollback), carrying ``uid`` and usually ``slot``.
@@ -19,8 +23,9 @@ The serving stack is instrumented with three record kinds:
 The buffer is a fixed-capacity deque: once full, the OLDEST records drop
 (``dropped`` counts them), so a long soak keeps the most recent window
 instead of growing without bound. A disabled tracer is falsy — callers
-hold ``None`` (or a falsy tracer) and guard every instrumentation site
-with one branch, which is the whole disabled-mode cost.
+hold ``None`` (or a falsy tracer) and guard every record site with one
+branch; a `Span` still writes its profiler annotation (about a
+microsecond).
 
 Exporters: `to_jsonl` (one header record + one record per line — the
 format `launch.trace_report` and `schema.validate_events` consume) and
@@ -30,13 +35,17 @@ tracing: one track per slot, one per engine phase).
 from __future__ import annotations
 
 import collections
-import contextlib
 import json
 import time
+
+from jax.profiler import TraceAnnotation
 
 from repro.obs.atomic import atomic_write_text
 
 SCHEMA_VERSION = 1
+
+#: profiler annotation of phase ``p`` is ``ANNOTATION_PREFIX + p``
+ANNOTATION_PREFIX = "repro."
 
 #: Chrome-trace thread ids: slots get 1 + slot, un-slotted lifecycle
 #: events a "requests" track, un-slotted phase spans one track per phase
@@ -90,19 +99,19 @@ class Tracer:
     def span_end(self, name: str, t_begin: float, **fields) -> None:
         """Record a span from ``t_begin`` (a `begin`/clock timestamp) to
         now. Extra ``fields`` ride along (slot/uid/step/dispatch_s/...)."""
+        self.record_span(name, t_begin, self.clock() - t_begin, **fields)
+
+    def record_span(self, name: str, t_begin: float, dur: float,
+                    **fields) -> None:
+        """Record a span of ``dur`` seconds from ``t_begin``."""
         if not self.enabled:
             return
         self._push({"kind": "span", "name": name,
-                    "ts": t_begin - self.t0,
-                    "dur": self.clock() - t_begin, **fields})
+                    "ts": t_begin - self.t0, "dur": dur, **fields})
 
-    @contextlib.contextmanager
-    def span(self, name: str, **fields):
-        t_begin = self.clock()
-        try:
-            yield
-        finally:
-            self.span_end(name, t_begin, **fields)
+    def span(self, name: str, **fields) -> "Span":
+        """A `Span` of phase ``name`` recorded into this tracer."""
+        return Span(self, name, self.clock, **fields)
 
     def event(self, name: str, **fields) -> None:
         if not self.enabled:
@@ -140,6 +149,54 @@ class Tracer:
     def to_chrome(self, path: str) -> None:
         atomic_write_text(
             path, json.dumps(chrome_trace(list(self.records()))))
+
+
+class Span:
+    """One phase on the profiler's clock, used as a context manager.
+
+    Entering opens the profiler annotation ``repro.<name>`` with ``args``
+    as its arguments (one cheap call when no profiler session is
+    running); leaving closes it and sets ``dur`` from ``clock``. With a
+    truthy ``tracer`` the span is also recorded into its ring buffer,
+    on the tracer's clock, with ``args`` plus whatever `note` added —
+    also when the body raises. There is no other way the engine records
+    a span, so every ring-buffer span is also a profiler annotation.
+    """
+
+    __slots__ = ("tracer", "name", "clock", "args", "fields", "t0", "dur",
+                 "_ann")
+
+    def __init__(self, tracer, name: str, clock=time.perf_counter,
+                 **args):
+        self.tracer = tracer if tracer else None
+        self.name = name
+        self.clock = tracer.clock if self.tracer else clock
+        self.args = args
+        self.fields = None
+        self.t0 = self.dur = 0.0
+
+    def note(self, **fields) -> None:
+        """Fields for the ring-buffer record that are known only inside
+        the span (dispatch_s, wait_s, ...)."""
+        if self.fields is None:
+            self.fields = fields
+        else:
+            self.fields.update(fields)
+
+    def __enter__(self) -> "Span":
+        self._ann = TraceAnnotation(ANNOTATION_PREFIX + self.name,
+                                    **self.args)
+        self._ann.__enter__()
+        self.t0 = self.clock()
+        return self
+
+    def __exit__(self, *exc) -> bool:
+        self.dur = self.clock() - self.t0
+        if self.tracer is not None:
+            self.tracer.record_span(self.name, self.t0, self.dur,
+                                    **self.args, **(self.fields or {}))
+        self._ann.__exit__(*exc)
+        return False
 
 
 def load_jsonl(path: str) -> list[dict]:

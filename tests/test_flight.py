@@ -96,6 +96,34 @@ def test_latency_spike_warmup_and_cooldown():
     assert det.n_fired == 2
 
 
+def test_latency_spike_says_device_or_host_wait():
+    """A record carrying the decode readback's wait_s gets a firing that
+    splits the slow step's wall into device wait and host time."""
+    det = AnomalyDetector(cooldown_steps=5, warmup_steps=2,
+                          latency_factor=4.0)
+    for s in range(3):
+        det.sweep({"step": s, "step_s": 0.2, "wait_s": 0.19})
+    fired = det.sweep({"step": 3, "step_s": 1.6, "wait_s": 0.2})
+    assert [f.detector for f in fired] == ["step_latency_spike"]
+    assert "0.2000s of it waiting on the device" in fired[0].reason
+    assert "1.4000s on the host" in fired[0].reason
+
+
+def test_flight_record_carries_readback_wait(setup):
+    """Every step record has wait_s: the step's readbacks (decode tokens
+    and first tokens), inside the step's wall."""
+    cfg, model, params, prompts = setup
+    eng = Engine(cfg, params, EngineConfig(
+        n_slots=2, max_len=MAX_LEN, prefill_bucket=8))
+    for p in prompts:
+        eng.submit(p, max_new_tokens=4)
+    eng.drain()
+    recs = eng._flight.window()
+    assert recs and all("wait_s" in r for r in recs)
+    assert all(0.0 <= r["wait_s"] <= r["step_s"] + 1e-6 for r in recs)
+    assert any(r["wait_s"] > 0 for r in recs)
+
+
 def test_derived_detectors_fire_on_their_signals():
     det = AnomalyDetector(cooldown_steps=100, warmup_steps=99,
                           queue_set_point=4)

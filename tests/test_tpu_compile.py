@@ -8,9 +8,11 @@ stablelm-1.6b's serving widths: 32 query and 32 KV heads of 64 dims, an
 8-slot cache of 1024 positions, 96-token prefill chunks, 4 KV sub-channel
 chunks (plus one grouped-query prefill case with 8 KV heads), and one
 2048x5632 INT2 weight for the dequant-matmul. Each case also checks that
-the Pallas kernel is in the compiled program.
+the Pallas kernel is in the compiled program, and each attention kernel
+that it carries its own name, which a profiler trace shows.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -53,6 +55,13 @@ def compiled_text(fn, sharding, *shapes):
     return jax.jit(fn).lower(*args).compile().as_text()
 
 
+def kernel_names(text: str) -> list[str]:
+    """The op_name of each Pallas call in a compiled program."""
+    return [m.group(1) for line in text.splitlines()
+            if 'custom_call_target="tpu_custom_call"' in line
+            for m in [re.search(r'op_name="([^"]*)"', line)] if m]
+
+
 DECODE_CASES = {
     "fp": (dict(mode="fp"), jnp.bfloat16, None),
     "int8-dynamic": (dict(mode="int8"), jnp.int8, (N, T, HKV, C)),
@@ -78,7 +87,9 @@ def test_decode_attention_compiles(one_chip, case):
             return decode_attention(q, k, v, kv_pos, q_pos, k_scale=ks,
                                     k_zero=kz, v_scale=vs, v_zero=vz,
                                     use_pallas=True, **kw)
-    assert "tpu_custom_call" in compiled_text(fn, one_chip, *shapes)
+    names = kernel_names(compiled_text(fn, one_chip, *shapes))
+    assert names and all(n.endswith("/decode_attention/pallas_call")
+                         for n in names), names
 
 
 PREFILL_CASES = {
@@ -113,7 +124,9 @@ def test_prefill_attention_compiles(one_chip, case):
                                      length, k_scale=ks, k_zero=kz,
                                      v_scale=vs, v_zero=vz, use_pallas=True,
                                      **kw)
-    assert "tpu_custom_call" in compiled_text(fn, one_chip, *shapes)
+    names = kernel_names(compiled_text(fn, one_chip, *shapes))
+    assert names and all(n.endswith("/prefill_attention/pallas_call")
+                         for n in names), names
 
 
 def test_splitquant_matmul_compiles(one_chip):
