@@ -49,6 +49,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.kernels.ops import pack_weights
 from repro.models import get_model
 from repro.obs.tracer import Span
 
@@ -343,7 +344,6 @@ class Engine:
                 "windowed (ring) slot caches not wired up yet; "
                 f"window={cfg.window} < max_len={ecfg.max_len}")
         self.cfg = cfg
-        self.params = params
         self.ecfg = ecfg
         self.model = get_model(cfg)
         self.clock = clock
@@ -541,6 +541,24 @@ class Engine:
                                               tracer=self.tracer,
                                               registry=self.registry)
             self._verify = spec_mod.jitted_verify(cfg)
+        # the served weights, packed once into the dequant-matmul's layout
+        # (kernels/ops.pack_weights) after any draft tree was minted from
+        # the unpacked ones: the decode and chunk steps repack nothing
+        self.params, (n_packed, n_bytes, n_left) = pack_weights(params)
+        if self._mx is not None:
+            r = self.registry
+            r.gauge("engine_packed_weight_leaves",
+                    "quantized weights packed once at engine start").set(
+                        n_packed)
+            r.gauge("engine_packed_weight_bytes",
+                    "device bytes of the packed weights").set(n_bytes)
+            r.gauge("engine_unpacked_quant_leaves",
+                    "quantized leaves left unpacked (dequantized on every "
+                    "call)").set(n_left)
+        if n_packed or n_left:
+            print(f"[engine] packed weights: {n_packed} leaves, {n_bytes} "
+                  f"bytes; {n_left} quantized leaves left unpacked",
+                  file=sys.stderr)
         # host-side slot state
         N = ecfg.n_slots
         self._last_tok = np.zeros(N, np.int32)

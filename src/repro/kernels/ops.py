@@ -1,12 +1,14 @@
 """Public jit'd wrappers around the SplitQuant kernels.
 
 `linear()` is the single entry point models use: it dispatches on the weight
-leaf type (dense array vs SplitQuantTensor) and on the backend (Pallas TPU
-kernel vs an XLA-fused jnp dequant-matmul — the latter also serves
-CPU/dry-run, and is what serving runs today).
+leaf type (dense array, SplitQuantTensor, or a PackedSplitQuantTensor built
+once by `pack_weights`) and on the backend (Pallas TPU kernel vs an
+XLA-fused jnp dequant-matmul — the latter also serves CPU/dry-run, and is
+what serving runs today).
 """
 from __future__ import annotations
 
+import dataclasses
 import functools
 from typing import Union
 
@@ -18,20 +20,66 @@ from .packing import pack_cids, pack_codes, unpack_cids, unpack_codes
 from .splitquant_matmul import select_per_cluster, splitquant_matmul
 
 
+@functools.partial(jax.tree_util.register_dataclass,
+                   data_fields=("q_packed", "cid_packed", "recip", "shift"),
+                   meta_fields=("bits", "k", "orig_shape", "orig_dtype"))
+@dataclasses.dataclass
+class PackedSplitQuantTensor:
+    """A 2-D-per-matrix SplitQuantTensor in the layout the dequant-matmul
+    reads (`pack_for_kernel`), with the stack axes leading: a (*stack, K, N)
+    weight holds (*stack, K·bits/8, N) packed codes, (*stack, K/4, N)
+    packed cluster ids and (*stack, k, N) fp32 ``recip``/``shift``, so a
+    layer scan's slice is exactly the operand `quantized_matmul` takes."""
+
+    q_packed: jnp.ndarray
+    cid_packed: jnp.ndarray
+    recip: jnp.ndarray
+    shift: jnp.ndarray
+    bits: int
+    k: int
+    orig_shape: tuple
+    orig_dtype: object
+
+    @property
+    def nbytes(self) -> int:
+        return sum(a.nbytes for a in (self.q_packed, self.cid_packed,
+                                      self.recip, self.shift))
+
+    def dequantize(self) -> jnp.ndarray:
+        """The dense ŵ the dequant-matmul multiplies by, in orig_dtype."""
+        fn = functools.partial(_dequant_packed, bits=self.bits, k=self.k,
+                               dtype=self.orig_dtype)
+        for _ in range(self.q_packed.ndim - 2):
+            fn = jax.vmap(fn)
+        return fn(self.q_packed, self.cid_packed, self.recip, self.shift)
+
+
+#: the weight leaves `linear` runs through the dequant-matmul
+QUANTIZED = (SplitQuantTensor, PackedSplitQuantTensor)
+
+
 def _round_up(v: int, m: int) -> int:
     return (v + m - 1) // m * m
 
 
-def _xla_matmul(x, q_packed, cid_packed, recip, shift, bits: int, k: int):
-    """The XLA serving path: dequantize the packed weight to x's dtype,
-    selecting each element's cluster constants by a masked sum over the k
-    clusters (a gather of one constant per weight element runs orders of
-    magnitude slower on TPU), then one dense matmul accumulated in fp32.
-    Checked against the plain-gather oracle in `ref`."""
+def _dequant_packed(q_packed, cid_packed, recip, shift, *, bits: int,
+                    k: int, dtype):
+    """(K, N) ŵ = q·recip[cid] + shift[cid] in ``dtype``, each element's
+    cluster constants selected by a masked sum over the k clusters (a
+    gather of one constant per weight element runs orders of magnitude
+    slower on TPU)."""
     q = unpack_codes(q_packed, bits).astype(jnp.float32)          # (K, N)
     cid = unpack_cids(cid_packed)                                 # (K, N)
-    w = (q * select_per_cluster(recip, cid, k)
-         + select_per_cluster(shift, cid, k)).astype(x.dtype)
+    return (q * select_per_cluster(recip, cid, k)
+            + select_per_cluster(shift, cid, k)).astype(dtype)
+
+
+def _xla_matmul(x, q_packed, cid_packed, recip, shift, bits: int, k: int):
+    """The XLA serving path: dequantize the packed weight to x's dtype,
+    then one dense matmul accumulated in fp32. Checked against the
+    plain-gather oracle in `ref`."""
+    w = _dequant_packed(q_packed, cid_packed, recip, shift, bits=bits, k=k,
+                        dtype=x.dtype)
     return jnp.dot(x, w, preferred_element_type=jnp.float32).astype(x.dtype)
 
 
@@ -58,6 +106,44 @@ def pack_for_kernel(sqt: SplitQuantTensor):
     cp = pack_cids(sqt.cid)
     recip, shift = dequant_constants(sqt)
     return qp, cp, recip, shift
+
+
+def pack_weight(sqt: SplitQuantTensor) -> PackedSplitQuantTensor:
+    """`pack_for_kernel` over every matrix of a (possibly stacked)
+    SplitQuantTensor whose per-matrix shape is 2-D."""
+    assert len(sqt.orig_shape) == 2, sqt.orig_shape
+    fn = pack_for_kernel
+    for _ in range(sqt.stack_dims):
+        fn = jax.vmap(fn)
+    qp, cp, recip, shift = fn(sqt)
+    return PackedSplitQuantTensor(qp, cp, recip, shift, bits=sqt.bits,
+                                  k=sqt.k, orig_shape=sqt.orig_shape,
+                                  orig_dtype=sqt.orig_dtype)
+
+
+_pack_weight_jit = jax.jit(pack_weight)
+
+
+def pack_weights(params):
+    """Pack every SplitQuantTensor of ``params`` whose per-matrix shape is
+    2-D, once, into the layout the dequant-matmul reads (a serving step
+    then reads 0.5 B a weight element at INT2 and repacks nothing); every
+    other leaf is left as it is. Returns the tree and (packed leaves,
+    their bytes, quantized leaves left unpacked)."""
+    is_sqt = lambda leaf: isinstance(leaf, SplitQuantTensor)
+    leaves, treedef = jax.tree_util.tree_flatten(params, is_leaf=is_sqt)
+    n_packed = n_bytes = n_left = 0
+    for i, leaf in enumerate(leaves):
+        if not is_sqt(leaf):
+            continue
+        if len(leaf.orig_shape) != 2:
+            n_left += 1
+            continue
+        leaves[i] = _pack_weight_jit(leaf)
+        n_packed += 1
+        n_bytes += leaves[i].nbytes
+    return (jax.tree_util.tree_unflatten(treedef, leaves),
+            (n_packed, n_bytes, n_left))
 
 
 @functools.partial(jax.jit, static_argnames=("bits", "k", "use_pallas",
@@ -95,7 +181,8 @@ def quantized_matmul(x, q_packed, cid_packed, recip, shift, *, bits: int,
     return y[:M, :N].reshape(*lead, N)
 
 
-def linear(x: jnp.ndarray, w: Union[jnp.ndarray, SplitQuantTensor],
+def linear(x: jnp.ndarray,
+           w: Union[jnp.ndarray, SplitQuantTensor, PackedSplitQuantTensor],
            b=None, *, use_pallas: bool = False, interpret: bool = False):
     """Dense layer with transparent SplitQuant dispatch.
 
@@ -103,23 +190,28 @@ def linear(x: jnp.ndarray, w: Union[jnp.ndarray, SplitQuantTensor],
     packed weight dequantize to  qmin·recip + shift ≠ 0, but the matching x
     columns are zero-padded so the extra products are exactly 0.
 
-    A SplitQuantTensor's whole product, the per-call packing included,
-    runs under the name ``dequant_matmul`` (op_name metadata only), so a
-    profiler trace gives its device time.
+    A 2-D SplitQuantTensor is packed on every call; a
+    PackedSplitQuantTensor (`pack_weights`) goes straight to the matmul.
+    A quantized weight's whole product, any packing included, runs under
+    the name ``dequant_matmul`` (op_name metadata only), so a profiler
+    trace gives its device time.
     """
-    if isinstance(w, SplitQuantTensor):
+    if isinstance(w, QUANTIZED):
         with jax.named_scope("dequant_matmul"):
-            if w.q.ndim != 2:
-                wx = w.dequantize()
-                y = jnp.dot(x, wx.astype(x.dtype))
-            else:
-                qp, cp, recip, shift = pack_for_kernel(w)
-                y = quantized_matmul(x, qp, cp, recip, shift, bits=w.bits,
-                                     k=w.k, use_pallas=use_pallas,
+            if isinstance(w, SplitQuantTensor) and \
+                    w.q.ndim == 2 == len(w.orig_shape):
+                w = pack_weight(w)
+            if isinstance(w, PackedSplitQuantTensor) and \
+                    w.q_packed.ndim == 2:
+                y = quantized_matmul(x, w.q_packed, w.cid_packed, w.recip,
+                                     w.shift, bits=w.bits, k=w.k,
+                                     use_pallas=use_pallas,
                                      interpret=interpret)
+            else:
+                y = jnp.dot(x, w.dequantize().astype(x.dtype))
     else:
         y = jnp.dot(x, w)
     if b is not None:
-        bb = b.dequantize() if isinstance(b, SplitQuantTensor) else b
+        bb = b.dequantize() if isinstance(b, QUANTIZED) else b
         y = y + bb
     return y

@@ -5,7 +5,6 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from repro.core.splitquant import SplitQuantTensor
 from repro.kernels import ops
 
 
@@ -85,7 +84,7 @@ def tp_dense(x, w, b=None):
     weight is quantized/stacked oddly, or a bias is present.
     """
     if (not _HINTS_ON or b is not None or
-            isinstance(w, SplitQuantTensor) or w.ndim != 2 or x.ndim < 2):
+            isinstance(w, ops.QUANTIZED) or w.ndim != 2 or x.ndim < 2):
         return dense(x, w, b)
     mesh = jax.sharding.get_abstract_mesh()
     if mesh.empty or "model" not in mesh.axis_names:
@@ -120,8 +119,9 @@ def tp_dense(x, w, b=None):
 
 def dense(x, w, b=None):
     """Linear layer; dispatches to the quantized path for SplitQuantTensor
-    leaves (kernels/ops.py). Computation dtype follows x."""
-    if isinstance(w, SplitQuantTensor):
+    and PackedSplitQuantTensor leaves (kernels/ops.py). Computation dtype
+    follows x."""
+    if isinstance(w, ops.QUANTIZED):
         return ops.linear(x, w, b)
     y = jnp.dot(x, w.astype(x.dtype))
     if b is not None:
@@ -132,13 +132,13 @@ def dense(x, w, b=None):
 def materialize(w, dtype=None):
     """Dense view of a (possibly quantized) parameter, for ops that need the
     raw array (einsum over experts, depthwise conv taps, …)."""
-    if isinstance(w, SplitQuantTensor):
+    if isinstance(w, ops.QUANTIZED):
         w = w.dequantize()
     return w.astype(dtype) if dtype is not None else w
 
 
 def embed_lookup(table, ids):
-    if isinstance(table, SplitQuantTensor):
+    if isinstance(table, ops.QUANTIZED):
         table = table.dequantize()
     return jnp.take(table, ids, axis=0)
 

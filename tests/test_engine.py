@@ -398,3 +398,85 @@ def _drained(eng, prompts):
     for p in prompts:
         eng.submit(p)
     return eng.drain()
+
+
+# ------------------------------------------- weights packed at start -----
+@pytest.fixture(scope="module")
+def int2_params(setup):
+    """The reduced model with SplitQuant INT2 k=3 weights, as served."""
+    from repro.core import QuantConfig, QuantPolicy, quantize_tree
+    cfg, model, params, prompts = setup
+    qparams, _ = quantize_tree(KEY, params, QuantPolicy(
+        cfg=QuantConfig(bits=2), k=3, method="splitquant"))
+    return qparams
+
+
+def _packed_bytes(qparams) -> int:
+    """0.5 B a weight element at INT2 (codes and cluster ids) plus the
+    fp32 recip/shift of every cluster and column."""
+    from repro.core import SplitQuantTensor
+    leaves = jax.tree_util.tree_leaves(
+        qparams, is_leaf=lambda l: isinstance(l, SplitQuantTensor))
+    return sum(l.q.size * l.bits // 8 + l.q.size // 4
+               + 2 * 4 * l.k * l.q.size // l.orig_shape[0]
+               for l in leaves if isinstance(l, SplitQuantTensor))
+
+
+def _unpacked_ref_engine(cfg, qparams, ecfg):
+    """An engine whose jitted decode and chunk steps run over the unpacked
+    tree, packing every weight on every call."""
+    ref = Engine(cfg, qparams, ecfg)
+    ref.params = qparams
+    return ref
+
+
+def test_engine_packs_weights_once(setup, int2_params):
+    """With chunked prefill, the engine serves weights packed at start: it
+    holds no 2-D SplitQuantTensor, its gauges count the packed leaves and
+    their bytes, and its greedy tokens equal those of the same jitted
+    steps over the unpacked tree."""
+    from repro.core import SplitQuantTensor
+    from repro.kernels.ops import PackedSplitQuantTensor
+
+    cfg, model, _, prompts = setup
+    ecfg = EngineConfig(n_slots=3, max_len=MAX_LEN, max_new_tokens=NEW_TOKENS,
+                        prefill_bucket=8, prefill_chunk=8, kv_mode="int8")
+    eng = Engine(cfg, int2_params, ecfg)
+    leaves = jax.tree_util.tree_leaves(
+        eng.params, is_leaf=lambda l: isinstance(
+            l, (SplitQuantTensor, PackedSplitQuantTensor)))
+    n_packed = sum(isinstance(l, PackedSplitQuantTensor) for l in leaves)
+    assert n_packed > 0
+    assert not any(isinstance(l, SplitQuantTensor)
+                   and len(l.orig_shape) == 2 for l in leaves)
+    snap = eng.registry.snapshot()
+    assert snap["engine_packed_weight_leaves"] == n_packed
+    assert snap["engine_packed_weight_bytes"] == _packed_bytes(int2_params)
+    assert snap["engine_unpacked_quant_leaves"] == 0
+
+    ref = _unpacked_ref_engine(cfg, int2_params, ecfg)
+    assert [r.out for r in _drained(eng, prompts)] == \
+        [r.out for r in _drained(ref, prompts)]
+    assert eng.n_prefill_chunks > len(prompts)     # chunked prompts ran
+
+
+def test_spec_draft_keeps_unpacked_weights(setup, int2_params):
+    """spec_k > 0: the target drafts for itself from the unpacked tree it
+    was given (dequantized once to its dense ŵ), while it verifies with
+    the packed one, and the speculative greedy tokens equal plain greedy
+    decoding over the unpacked tree."""
+    from repro.core import dequantize_tree
+
+    cfg, model, _, prompts = setup
+    base = dict(n_slots=3, max_len=MAX_LEN, max_new_tokens=NEW_TOKENS,
+                prefill_bucket=8, prefill_chunk=8)
+    eng = Engine(cfg, int2_params, EngineConfig(**base, spec_k=2))
+    draft, want = (jax.tree_util.tree_flatten(t)
+                   for t in (eng._spec.params, dequantize_tree(int2_params)))
+    assert draft[1] == want[1]
+    for a, b in zip(draft[0], want[0]):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    ref = _unpacked_ref_engine(cfg, int2_params, EngineConfig(**base))
+    assert [r.out for r in _drained(eng, prompts)] == \
+        [r.out for r in _drained(ref, prompts)]
+    assert eng.n_spec_steps > 0

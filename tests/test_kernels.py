@@ -134,3 +134,43 @@ def test_batched_input_reshape():
     x = jax.random.normal(KEY, (2, 3, 256))
     y = ops.quantized_matmul(x, qp, cp, recip, shift, bits=4, k=3)
     assert y.shape == (2, 3, 128)
+
+
+@pytest.mark.parametrize("bits", [2, 4, 8])
+@pytest.mark.parametrize("k", [1, 3])
+@pytest.mark.parametrize("n_stack", [0, 3])
+def test_packed_leaf_matches_per_call_packing(bits, k, n_stack):
+    """A weight packed once (`pack_weights`) gives `linear` the product of
+    the SplitQuantTensor packed on every call bit for bit, on a 2-D leaf
+    and on each (K, N) slice a layer scan takes of a stacked (L, K, N)
+    leaf; its dense view (`materialize`) is the SplitQuantTensor's ŵ."""
+    from repro.models.common import materialize
+
+    key = jax.random.PRNGKey(10 * bits + k)
+    K, N = 256, 128
+    w = jax.random.normal(key, (n_stack, K, N) if n_stack else (K, N)) * 0.1
+    sq = splitquant_tensor(key, w, QuantConfig(bits=bits), k=k,
+                           stack_dims=1 if n_stack else 0)
+    tree, counts = ops.pack_weights({"w": sq, "norm": jnp.ones(N)})
+    packed = tree["w"]
+    assert isinstance(packed, ops.PackedSplitQuantTensor)
+    L = max(n_stack, 1)
+    assert packed.q_packed.shape == (n_stack,) * bool(n_stack) + (
+        K * bits // 8, N)
+    assert counts == (1, L * (K * bits // 8 + K // 4 + 2 * 4 * k) * N, 0)
+
+    x = jax.random.normal(key, (4, K), jnp.bfloat16)
+    if n_stack:
+        def scan_linear(wt):
+            return jax.lax.scan(lambda c, wl: (c, ops.linear(x, wl)), 0,
+                                wt)[1]
+        y_call, y_once = scan_linear(sq), scan_linear(packed)
+    else:
+        y_call, y_once = ops.linear(x, sq), ops.linear(x, packed)
+    np.testing.assert_array_equal(np.asarray(y_once, np.float32),
+                                  np.asarray(y_call, np.float32))
+    # the packed leaf keeps the kernel's affine form q·(1/s) + (-z/s),
+    # which equals (q - z)/s to float32 rounding
+    np.testing.assert_allclose(np.asarray(materialize(packed)),
+                               np.asarray(sq.dequantize()),
+                               rtol=1e-6, atol=1e-6)

@@ -34,6 +34,20 @@ def _op_names(compiled_text: str) -> list[str]:
     return re.findall(r'op_name="([^"]*)"', compiled_text)
 
 
+def _compiled_op_names(cfg, eng, params, entry: str) -> list[str]:
+    """op_names of the engine's decode ("step") or chunk executable,
+    compiled on the CPU over ``params``."""
+    if entry == "step":
+        fn = _jitted_entry_points(cfg, True, True)[0]
+        args = (params, eng.cache, jnp.zeros((2, 1), jnp.int32),
+                jnp.zeros(2, jnp.int32))
+    else:
+        fn = _jitted_chunk_prefill(cfg)
+        args = (params, eng.cache, jnp.zeros((1, 16), jnp.int32),
+                jnp.int32(0), jnp.int32(0), jnp.int32(5))
+    return _op_names(fn.lower(*args).compile().as_text())
+
+
 def _under(name: str, scope: str) -> bool:
     return re.search(rf"(^|[/(]){re.escape(scope)}([/)]|$)", name) \
         is not None
@@ -48,15 +62,7 @@ def test_executables_carry_scope_names(tiny, entry):
     eng = Engine(cfg, qparams, EngineConfig(
         n_slots=2, max_len=32, kv_mode="int8", prefill_chunk=16,
         prefill_bucket=8))
-    if entry == "step":
-        fn = _jitted_entry_points(cfg, True, True)[0]
-        args = (qparams, eng.cache, jnp.zeros((2, 1), jnp.int32),
-                jnp.zeros(2, jnp.int32))
-    else:
-        fn = _jitted_chunk_prefill(cfg)
-        args = (qparams, eng.cache, jnp.zeros((1, 16), jnp.int32),
-                jnp.int32(0), jnp.int32(0), jnp.int32(5))
-    names = _op_names(fn.lower(*args).compile().as_text())
+    names = _compiled_op_names(cfg, eng, eng.params, entry)
     assert any(n.startswith(f"jit({entry})/") for n in names)
     for scope in SCOPES + ("embed",):
         assert any(_under(n, scope) for n in names), scope
@@ -65,6 +71,26 @@ def test_executables_carry_scope_names(tiny, entry):
     # the dequant-matmul of the head counts under both names
     assert any(_under(n, "lm_head") and _under(n, "dequant_matmul")
                for n in names)
+
+
+@pytest.mark.parametrize("entry", ["step", "chunk"])
+def test_served_steps_repack_no_weight(tiny, entry):
+    """Over the weights the engine packed at start, every op of the
+    dequant-matmul lies in ``jit(quantized_matmul)``: the per-call packing
+    of the codes (shifts, ors and converts under ``dequant_matmul``
+    outside that jit), which the unpacked tree still compiles, is gone."""
+    cfg, qparams = tiny
+    eng = Engine(cfg, qparams, EngineConfig(
+        n_slots=2, max_len=32, kv_mode="int8", prefill_chunk=16,
+        prefill_bucket=8))
+
+    def packing(params):
+        return [n for n in _compiled_op_names(cfg, eng, params, entry)
+                if _under(n, "dequant_matmul")
+                and "jit(quantized_matmul)" not in n]
+
+    assert any(n.endswith("/shift_left") for n in packing(qparams))
+    assert packing(eng.params) == []
 
 
 def _host_spans(log_dir):
