@@ -1,16 +1,17 @@
-"""Plain float32 reference of the served decoder, and its lower-precision
-control. It imports nothing of the program and takes nothing the program
-made: the weights are derived again from the seed.
+"""The dense decoder: the plain float32 reference of the served model, its
+lower-precision control, and the operations and bytes its steps need.
+The architecture of every configuration file that names no other
+(``harness/arch.py``).
 
-The served weights are the program's `model.init` from the seed's key,
-quantized by SplitQuant (k-means into k clusters, one affine INTb
-quantizer per cluster) with keys split from the same key, one per leaf of
-the parameter tree in its flattened order. This module repeats those
-steps as plain jnp, leaf by leaf, and keeps the codes, cluster ids and
+The reference imports nothing of the program and takes nothing the
+program made: the weights are derived again from the seed. The served
+weights are the program's `model.init` from the seed's key, quantized by
+SplitQuant with keys split from the same key, one per leaf of the
+parameter tree in its flattened order. This module draws each leaf as
+the program's dense decoder with an untied head draws it and hands it to
+``harness/refquant.py``, which keeps the codes, cluster ids and
 per-cluster constants; each layer is dequantized to float32 only while
-it runs. The leaf order and the key splits are those of the program's
-dense decoder with an untied head, the only architecture this reference
-covers.
+it runs.
 
 The forward pass is the decoder of the configuration file: RMS norm
 (with the program's 1 + scale gain, scale initialised to 0) or LayerNorm
@@ -21,35 +22,41 @@ K/V heads, SwiGLU feed-forward, final norm and head. Every matmul runs at
 ``precision=HIGHEST``. With ``control=True`` every matmul's two operands
 are first rounded to float8 (e4m3, one scale per tensor): the nearest
 precision below the bfloat16 the configurations state.
+
+The counts (below the reference) are what the algorithm needs, from
+shapes, as ``harness/counts.py`` states: a SplitQuant weight element is
+its code plus its cluster id, bits + ceil(log2 k) bits; the int8 KV
+cache stores one byte per element and an fp32 scale and zero per
+sub-channel chunk.
 """
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+from harness.counts import _chunk_keys
+from harness.refquant import dequant, mm, quantize_leaf, seed_key
 
 #: the parameters of one norm, by the configuration's norm type, in
 #: flattened (sorted-key) order
 NORM_LEAVES = {"rms": ("norm_scale",), "layer": ("norm_bias", "norm_scale")}
 NORM_EPS = {"rms": 1e-6, "layer": 1e-5}
 QUANTIZED = ("wk", "wo", "wq", "wv", "w_down", "w_gate", "w_up", "lm_head")
-SAMPLE_SIZE = 1 << 18
-KMEANS_ITERS = 25
-KMEANS_CANDIDATES = 4
 QUERY_BLOCK = 256
+#: fields of the program's ArchConfig that this reference and its counts
+#: do not model, at the values that leave the program a dense decoder;
+#: fields a dense decoder never reads (capacity_factor, block_pattern,
+#: conv_width, lru_width, n_enc_layers, ...) may take any value
+ASSUMED = {"family": "dense", "n_experts": 0, "top_k": 0,
+           "n_shared_experts": 0, "first_k_dense": 0, "dense_d_ff": 0,
+           "window": None, "ffn_type": "swiglu", "bias": False,
+           "tie_embeddings": False}
 #: share of each head's channels that RoPE rotates, by the program's name
 ROPE_FRACTION = {"full": 1.0, "half": 0.5}
-
-
-def seed_key(seed: int) -> jax.Array:
-    """The build key of a run: a typed "rbg" key (the TPU's own random
-    bit generator; threefry spends half a minute on 1.6e9 normals) from
-    31 bits drawn from the seed, so any whole-number seed (also past 32
-    bits) gives a valid key."""
-    state = np.random.SeedSequence(int(seed)).generate_state(1, np.uint32)
-    return jax.random.key(int(state[0]) >> 1, impl="rbg")
 
 
 def arch(conf: dict) -> tuple:
@@ -114,86 +121,6 @@ def _init_leaf(key, a, name):
     return jax.vmap(one)(jax.random.split(kl, L))
 
 
-def _kmeans_centroids(key, x, k):
-    """Lloyd's k-means on 1-D points after greedy k-means++ seeding
-    (candidates drawn in proportion to the squared distance, the one that
-    lowers the cost most kept); centroids sorted ascending."""
-    n = x.shape[0]
-    k0, key = jax.random.split(key)
-    first = x[jax.random.randint(k0, (), 0, n)]
-    centers = jnp.full((k,), first, dtype=x.dtype)
-    d2 = (x - first) ** 2
-
-    def pick(carry, key_i):
-        centers, d2, i = carry
-        total = jnp.sum(d2)
-        logits = jnp.where(total > 0, jnp.log(jnp.maximum(d2, 1e-30)),
-                           jnp.zeros_like(d2))
-        cand = x[jax.random.categorical(key_i, logits,
-                                        shape=(KMEANS_CANDIDATES,))]
-        cost = jnp.sum(jnp.minimum(d2[:, None],
-                                   (x[:, None] - cand[None, :]) ** 2), axis=0)
-        chosen = cand[jnp.argmin(cost)]
-        return (centers.at[i].set(chosen),
-                jnp.minimum(d2, (x - chosen) ** 2), i + 1), None
-
-    (centers, _, _), _ = jax.lax.scan(pick, (centers, d2, 1),
-                                      jax.random.split(key, k - 1))
-
-    def lloyd(centers, _):
-        assign = jnp.argmin((x[:, None] - centers[None, :]) ** 2, axis=1)
-        one_hot = jax.nn.one_hot(assign, k, dtype=jnp.float32)
-        counts = one_hot.sum(axis=0)
-        sums = one_hot.T @ x
-        return jnp.where(counts > 0, sums / jnp.maximum(counts, 1),
-                         centers), None
-
-    centers, _ = jax.lax.scan(lloyd, centers, None, length=KMEANS_ITERS)
-    return jnp.sort(centers)
-
-
-def _quantize_one(key, w, bits, k):
-    """SplitQuant of one matrix: cluster ids by nearest centroid (fit on at
-    most SAMPLE_SIZE strided samples), each cluster's range [min, max]
-    mapped affinely onto the 2^bits codes (S = (2^b - 1) / span, Z =
-    -2^(b-1) - rint(S * min); a single-valued cluster takes S = 1 / |v|)."""
-    wf = w.astype(jnp.float32)
-    flat = wf.reshape(-1)
-    n = flat.shape[0]
-    sample = flat[::n // SAMPLE_SIZE][:SAMPLE_SIZE] if n > SAMPLE_SIZE \
-        else flat
-    centroids = jax.jit(_kmeans_centroids, static_argnums=2)(key, sample, k)
-    cid = jnp.argmin((wf[..., None] - centroids) ** 2,
-                     axis=-1).astype(jnp.uint8)
-    big = jnp.asarray(jnp.finfo(jnp.float32).max, jnp.float32)
-
-    def cluster_range(c):
-        m = cid.reshape(-1) == c
-        empty = ~jnp.any(m)
-        lo = jnp.min(jnp.where(m, flat, big))
-        hi = jnp.max(jnp.where(m, flat, -big))
-        return jnp.where(empty, 0.0, lo), jnp.where(empty, 0.0, hi)
-
-    beta, alpha = jax.vmap(cluster_range)(jnp.arange(k))
-    span = alpha - beta
-    amax = jnp.maximum(jnp.abs(beta), jnp.abs(alpha))
-    single = jnp.where(amax > 0, 1.0 / jnp.where(amax > 0, amax, 1.0), 1.0)
-    scale = jnp.where(span > 0, (2 ** bits - 1) / jnp.where(span > 0, span,
-                                                            1.0), single)
-    zero = -(2 ** (bits - 1)) - jnp.rint(scale * beta)
-    q = jnp.rint(_select(scale, cid) * wf) + _select(zero, cid)
-    q = jnp.clip(q, -(2 ** (bits - 1)), 2 ** (bits - 1) - 1).astype(jnp.int8)
-    return q, cid, scale, zero
-
-
-@functools.partial(jax.jit, static_argnames=("bits", "k", "stacked"))
-def _quantize_leaf(key, w, bits, k, stacked):
-    fn = functools.partial(_quantize_one, bits=bits, k=k)
-    if stacked:
-        return jax.vmap(fn)(jax.random.split(key, w.shape[0]), w)
-    return fn(key, w)
-
-
 def derive_weights(conf: dict, seed: int) -> dict:
     """{leaf: (codes, cluster ids, scales, zeros)} for the quantized
     leaves and the embedding table as served, one leaf live at a time."""
@@ -205,44 +132,15 @@ def derive_weights(conf: dict, seed: int) -> dict:
     out = {"embed": _init_leaf(key, a, "embed")}
     for name in QUANTIZED:
         w = _init_leaf(key, a, name)
-        out[name] = _quantize_leaf(keys[order.index(name)], w,
-                                   bits=q["bits"], k=q["k"],
-                                   stacked=name != "lm_head")
+        out[name] = quantize_leaf(keys[order.index(name)], w,
+                                  bits=q["bits"], k=q["k"],
+                                  stacked=name != "lm_head")
         jax.block_until_ready(out[name])
         del w
     return out
 
 
-def _select(vals, cid):
-    """vals[cid] per element, as a masked sum over the clusters: an
-    elementwise pass, where a gather of one value per element is slow on
-    a TPU."""
-    return sum(jnp.where(cid == c, vals[..., c], 0.0)
-               for c in range(vals.shape[-1]))
-
-
-def _dequant(leaf, dtype):
-    q, cid, scale, zero = leaf
-    w = (q.astype(jnp.float32) - _select(zero, cid)) / _select(scale, cid)
-    return w.astype(dtype).astype(jnp.float32)
-
-
 # --------------------------------------------------------------- forward --
-def _fp8(x):
-    s = jnp.max(jnp.abs(x))
-    s = jnp.where(s > 0, s / 448.0, 1.0)
-    return (x / s).astype(jnp.float8_e4m3fn).astype(jnp.float32) * s
-
-
-def _mm(eq, a, b, control):
-    if control:
-        return jnp.einsum(eq, _fp8(a).astype(jnp.bfloat16),
-                          _fp8(b).astype(jnp.bfloat16),
-                          preferred_element_type=jnp.float32)
-    return jnp.einsum(eq, a, b, precision=jax.lax.Precision.HIGHEST,
-                      preferred_element_type=jnp.float32)
-
-
 def _norm(x, a):
     """The norm at its initial parameters: RMS (gain 1 + 0) or LayerNorm
     (gain 1, bias 0)."""
@@ -277,11 +175,11 @@ def _attention(q, k, v, a, control):
     outs = []
     for s in range(0, T, QUERY_BLOCK):
         qb = q[s:s + QUERY_BLOCK] * D ** -0.5
-        sc = _mm("qhd,thd->hqt", qb, k, control)
+        sc = mm("qhd,thd->hqt", qb, k, control)
         qi = s + jnp.arange(qb.shape[0])[:, None]
         sc = jnp.where(jnp.arange(T)[None, :] <= qi, sc, -jnp.inf)
         p = jax.nn.softmax(sc, axis=-1)
-        outs.append(_mm("hqt,thd->qhd", p, v, control))
+        outs.append(mm("hqt,thd->qhd", p, v, control))
     return jnp.concatenate(outs, axis=0)
 
 
@@ -291,20 +189,20 @@ def _layer(x, wts, layer, a, control):
     each stacked leaf inside the program."""
     B, T, d = x.shape
     Hq, Hkv, D = a[2], a[3], a[4]
-    w = {n: _dequant(tuple(jax.lax.dynamic_index_in_dim(t, layer, 0, False)
-                           for t in wts[n]), a[9])
+    w = {n: dequant(tuple(jax.lax.dynamic_index_in_dim(t, layer, 0, False)
+                          for t in wts[n]), a[9])
          for n in ("wq", "wk", "wv", "wo", "w_gate", "w_up", "w_down")}
     h = _norm(x, a)
-    q = _rope(_mm("btd,de->bte", h, w["wq"], control).reshape(B, T, Hq, D), a)
-    k = _rope(_mm("btd,de->bte", h, w["wk"], control).reshape(B, T, Hkv, D),
+    q = _rope(mm("btd,de->bte", h, w["wq"], control).reshape(B, T, Hq, D), a)
+    k = _rope(mm("btd,de->bte", h, w["wk"], control).reshape(B, T, Hkv, D),
               a)
-    v = _mm("btd,de->bte", h, w["wv"], control).reshape(B, T, Hkv, D)
+    v = mm("btd,de->bte", h, w["wv"], control).reshape(B, T, Hkv, D)
     o = jax.lax.map(lambda qkv: _attention(*qkv, a, control), (q, k, v))
-    x = x + _mm("bte,ed->btd", o.reshape(B, T, Hq * D), w["wo"], control)
+    x = x + mm("bte,ed->btd", o.reshape(B, T, Hq * D), w["wo"], control)
     h = _norm(x, a)
-    g = jax.nn.silu(_mm("btd,df->btf", h, w["w_gate"], control))
-    u = _mm("btd,df->btf", h, w["w_up"], control)
-    return x + _mm("btf,fd->btd", g * u, w["w_down"], control)
+    g = jax.nn.silu(mm("btd,df->btf", h, w["w_gate"], control))
+    u = mm("btd,df->btf", h, w["w_up"], control)
+    return x + mm("btf,fd->btd", g * u, w["w_down"], control)
 
 
 @functools.partial(jax.jit, static_argnames=("a", "control"))
@@ -312,7 +210,7 @@ def _head(h, head, a, control, tok, ctl):
     """Logit statistics of rows h (R, d): with control, each row's argmax;
     else each row's best logit minus the logit of `tok` and of `ctl`, in
     units of the row's standard deviation."""
-    logits = _mm("rd,dv->rv", _norm(h, a), _dequant(head, a[9]), control)
+    logits = mm("rd,dv->rv", _norm(h, a), dequant(head, a[9]), control)
     if control:
         return jnp.argmax(logits, axis=-1).astype(jnp.int32)
     best = jnp.max(logits, axis=-1)
@@ -387,5 +285,113 @@ def logits(conf: dict, wts: dict, seqs) -> np.ndarray:
     reference itself at small sizes."""
     a = arch(conf)
     x = _hidden(conf, wts, seqs, False)
-    return np.asarray(_mm("btd,dv->btv", _norm(x, a),
-                          _dequant(wts["lm_head"], a[9]), False))
+    return np.asarray(mm("btd,dv->btv", _norm(x, a),
+                         dequant(wts["lm_head"], a[9]), False))
+
+
+# ---------------------------------------------------------------- counts --
+ACT_BYTES = 2        # bfloat16 activations (q, k, v, attention output)
+
+
+def layer_matrix_elements(c: dict) -> int:
+    d, Hq, Hkv, D, ff = (c["d_model"], c["n_heads"], c["n_kv_heads"],
+                         c["head_dim"], c["d_ff"])
+    return d * Hq * D + 2 * d * Hkv * D + Hq * D * d + 3 * d * ff
+
+
+def weight_elements(c: dict) -> int:
+    """Matmul weight elements of the model: every layer and the head."""
+    return c["n_layers"] * layer_matrix_elements(c) \
+        + c["d_model"] * c["vocab"]
+
+
+def weight_bytes(c: dict) -> float:
+    """Least bytes to read every quantized matrix once: codes and cluster
+    ids, plus each matrix's per-cluster scale and zero (fp32)."""
+    q = c["quant"]
+    per_elt = (q["bits"] + math.ceil(math.log2(q["k"]))) / 8
+    n_mats = 7 * c["n_layers"] + 1
+    return weight_elements(c) * per_elt + n_mats * q["k"] * 2 * 4
+
+
+def kv_bytes_per_token_layer(c: dict) -> int:
+    """Stored bytes of one token's K and V in one layer."""
+    Hkv, D = c["n_kv_heads"], c["head_dim"]
+    if c["kv_mode"] == "int8":
+        return 2 * (Hkv * D + Hkv * c["kv_qchunks"] * 2 * 4)
+    return 2 * Hkv * D * ACT_BYTES
+
+
+def kv_bytes_per_token(c: dict) -> int:
+    return c["n_layers"] * kv_bytes_per_token_layer(c)
+
+
+def attn_flops(c: dict, keys: int) -> float:
+    """QK^T and PV of one query over `keys` keys, every layer."""
+    return 4.0 * c["n_layers"] * c["n_heads"] * c["head_dim"] * keys
+
+
+def token_flops(c: dict, keys: int) -> float:
+    """Model FLOPs of one token that attends `keys` keys: 2 per matmul
+    weight element plus attention."""
+    return 2.0 * weight_elements(c) + attn_flops(c, keys)
+
+
+# ----------------------------------------------------------- decode step --
+def decode_step_bytes(c: dict, positions) -> float:
+    """Least bytes of one decode step whose active slots sit at
+    `positions`: the weights once, the embedding rows and norm vectors
+    (a gain, and a bias under LayerNorm), each active slot's live KV rows
+    (positions 0..p) read and its new row written."""
+    n = len(positions)
+    d, L = c["d_model"], c["n_layers"]
+    per_norm = 2 if c["norm"] == "layer" else 1
+    unquantized = n * d * 2 + (2 * L + 1) * per_norm * d * 2
+    kv = kv_bytes_per_token(c)
+    return weight_bytes(c) + unquantized \
+        + sum(p + 1 for p in positions) * kv + n * kv
+
+
+def decode_step_flops(c: dict, positions) -> float:
+    return sum(token_flops(c, p + 1) for p in positions)
+
+
+def decode_attn_cost(c: dict, positions) -> tuple[float, float]:
+    """(FLOPs, bytes) of the decode-attention kernel over every layer of
+    one step: live rows' codes and scales, q and the output."""
+    L, Hq, D = c["n_layers"], c["n_heads"], c["head_dim"]
+    keys = sum(p + 1 for p in positions)
+    flops = attn_flops(c, keys)
+    bytes_ = keys * kv_bytes_per_token(c) \
+        + len(positions) * L * 2 * Hq * D * ACT_BYTES
+    return flops, bytes_
+
+
+# ---------------------------------------------------------- chunk prefill --
+def chunk_flops(c: dict, pos_start: int, n: int) -> float:
+    """n prompt tokens through every layer, and the head for the chunk's
+    last token (the only logits row a chunk needs)."""
+    d, V = c["d_model"], c["vocab"]
+    return 2.0 * n * (weight_elements(c) - d * V) + 2.0 * d * V \
+        + attn_flops(c, _chunk_keys(pos_start, n))
+
+
+def chunk_bytes(c: dict, pos_start: int, n: int) -> float:
+    """The weights once, n embedding rows, the slot's prefix rows read and
+    the chunk's rows written."""
+    d = c["d_model"]
+    kv = kv_bytes_per_token(c)
+    return weight_bytes(c) + n * d * 2 + pos_start * kv + n * kv
+
+
+def prefill_attn_cost(c: dict, pos_start: int, n: int) -> tuple[float, float]:
+    """(FLOPs, bytes) of the prefill-attention kernel over every layer of
+    one chunk: the prefix rows read, the chunk's q, k, v in and output
+    out, its codes and scales written."""
+    L, Hq, Hkv, D = (c["n_layers"], c["n_heads"], c["n_kv_heads"],
+                     c["head_dim"])
+    flops = attn_flops(c, _chunk_keys(pos_start, n))
+    kv = kv_bytes_per_token(c)
+    bytes_ = pos_start * kv + n * kv \
+        + L * n * (2 * Hq + 2 * Hkv) * D * ACT_BYTES
+    return flops, bytes_

@@ -1,3 +1,6 @@
 """The chip benchmark's yardstick: spec loading, traffic, the closed loop,
-trace reduction, FLOP and byte counts, the plain reference and the
-output check. Nothing here is imported by the program under test."""
+trace reduction, the peaks and the roofline, the SplitQuant replica every
+reference shares, and the output check. Each architecture's plain
+reference and FLOP and byte counts are its own module under
+``architectures/``. Nothing here is imported by the program under
+test."""

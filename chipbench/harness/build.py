@@ -10,28 +10,43 @@ import time
 import jax
 import jax.numpy as jnp
 
-from .reference import seed_key
+from .refquant import seed_key
 
-#: configuration-file keys copied onto the program's ArchConfig
-ARCH_KEYS = {"n_layers": "n_layers", "d_model": "d_model",
-             "n_heads": "n_heads", "n_kv_heads": "n_kv_heads",
-             "d_ff": "d_ff", "vocab": "vocab", "rope_variant": "rope_variant",
-             "rope_theta": "rope_theta", "norm": "norm_type",
-             "ffn": "ffn_type", "param_dtype": "param_dtype"}
+#: configuration-file keys whose program field has another name
+ALIASES = {"norm": "norm_type", "ffn": "ffn_type",
+           "head_dim": "head_dim_override"}
+#: program fields a file does not set: its "name" and "source" document
+#: the configuration, and the program's registry entry keeps its own
+NOT_COPIED = ("name", "source")
+
+
+def program_keys() -> set[str]:
+    """Configuration-file keys that reach the program's ArchConfig: its
+    fields (but NOT_COPIED) under their own names, and ALIASES."""
+    from repro.configs import ArchConfig
+
+    fields = {f.name for f in dataclasses.fields(ArchConfig)}
+    return (fields - set(NOT_COPIED)) | set(ALIASES)
 
 
 def arch_config(conf: dict):
-    """The program's ArchConfig for `conf["arch"]`, with every size the
-    configuration file states."""
+    """The program's ArchConfig for `conf["arch"]`, with every program
+    field the configuration file states (a list as a tuple); `head_dim`
+    sets `head_dim_override` where the ArchConfig's own head_dim
+    differs. `bias` and `tie_embeddings` are False where the file leaves
+    them out."""
     from repro.configs import get_arch
 
-    cfg = dataclasses.replace(
-        get_arch(conf["arch"]),
-        **{dst: conf[src] for src, dst in ARCH_KEYS.items()},
-        bias=conf.get("bias", False),
-        tie_embeddings=conf.get("tie_embeddings", False))
-    if cfg.head_dim != conf["head_dim"]:
-        cfg = dataclasses.replace(cfg, head_dim_override=conf["head_dim"])
+    given = {"bias": False, "tie_embeddings": False}
+    keys = program_keys()
+    for key, value in conf.items():
+        if key in keys:
+            given[ALIASES.get(key, key)] = \
+                tuple(value) if isinstance(value, list) else value
+    head_dim = given.pop("head_dim_override", None)
+    cfg = dataclasses.replace(get_arch(conf["arch"]), **given)
+    if head_dim is not None and cfg.head_dim != head_dim:
+        cfg = dataclasses.replace(cfg, head_dim_override=head_dim)
     return cfg
 
 

@@ -3,8 +3,10 @@ engine returns its last one, driving ``Engine.submit`` / ``Engine.step``
 and nothing else.
 
 Every token is stamped on the client's side when ``Engine.step()``
-returns with it. The window is [t0, t1]: t0 just before its first step,
-t1 when the first step ending at or past ``t0 + seconds`` returns.
+returns with it; a request's slot is read with its first token, so that
+the output check can take a request from every slot. The window is
+[t0, t1]: t0 just before its first step, t1 when the first step ending
+at or past ``t0 + seconds`` returns.
 
 In a traced run two spies record what the program's jitted entry points
 are asked to do, for the per-layer FLOP and byte counts and the decode
@@ -38,6 +40,7 @@ class Served:
     stamps: list = dataclasses.field(default_factory=list)
     finished: bool = False
     reason: str | None = None
+    slot: int | None = None             # the engine's slot that served it
 
 
 @dataclasses.dataclass
@@ -113,6 +116,13 @@ class ClosedLoop:
         self.win.served.append(s)
         return s
 
+    def _slot_of(self, req) -> int | None:
+        """The slot that holds `req`, read once, with its first token."""
+        for i, r in enumerate(self.eng.sched.slots):
+            if r is req:
+                return i
+        return None
+
     def _step(self) -> float:
         with self.annotate("engine.step"):
             done = self.eng.step()
@@ -122,6 +132,8 @@ class ClosedLoop:
                 new = len(s.req.out) - len(s.stamps)
                 if new > 0:
                     s.stamps.extend([now] * new)
+                    if s.slot is None:
+                        s.slot = self._slot_of(s.req)
             for req in done:
                 s = self.live.pop(req.uid, None)
                 if s is None:

@@ -1,7 +1,10 @@
 """Device time of the Pallas decode-attention kernel per decode step (all
-layers), from the kernel's ops inside the decode executable."""
+layers), from the ops of the Pallas call named decode_attention
+inside the decode executable."""
 DECODE_FN = "step"
-KERNEL = r"tpu_custom_call"      # the one Pallas call in jit_step
+#: the Pallas call named decode_attention, by its HLO instruction or
+#: op_name; another Pallas call in jit_step is not read
+KERNEL = r"tpu_custom_call .*\bdecode_attention\b"
 
 
 def read(run):

@@ -4,7 +4,9 @@ over its summed device time."""
 from harness import counts
 
 DECODE_FN = "step"
-KERNEL = r"tpu_custom_call"      # the one Pallas call in jit_step
+#: the Pallas call named decode_attention, by its HLO instruction or
+#: op_name; another Pallas call in jit_step is not read
+KERNEL = r"tpu_custom_call .*\bdecode_attention\b"
 
 
 def read(run):

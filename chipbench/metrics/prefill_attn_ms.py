@@ -1,7 +1,10 @@
 """Device time of the Pallas prefill-attention kernel per chunk (all
-layers), from the kernel's ops inside the chunk-prefill executable."""
+layers), from the ops of the Pallas call named prefill_attention
+inside the chunk-prefill executable."""
 CHUNK_FN = "chunk"
-KERNEL = r"tpu_custom_call"      # the one Pallas call in jit_chunk
+#: the Pallas call named prefill_attention, by its HLO instruction or
+#: op_name; another Pallas call in jit_chunk is not read
+KERNEL = r"tpu_custom_call .*\bprefill_attention\b"
 
 
 def read(run):

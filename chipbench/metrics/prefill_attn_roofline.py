@@ -4,7 +4,9 @@ chunk) over its summed device time."""
 from harness import counts
 
 CHUNK_FN = "chunk"
-KERNEL = r"tpu_custom_call"      # the one Pallas call in jit_chunk
+#: the Pallas call named prefill_attention, by its HLO instruction or
+#: op_name; another Pallas call in jit_chunk is not read
+KERNEL = r"tpu_custom_call .*\bprefill_attention\b"
 
 
 def read(run):

@@ -20,7 +20,8 @@ the chip for its whole life and starts no other. In order:
    the trace is reduced to the per-layer metrics); compiles, cache loads, collector pauses and the
    longest steps inside the window are named on standard error;
 6. frees the program's state and checks a sample of what the window
-   served against the plain reference (``harness/reference.py``).
+   served against the plain reference of the configuration's
+   architecture (``architectures/<name>.py``, ``harness/check.py``).
 
 Set-up (``setup_s``) runs from the start of this process to the first
 timed step. The last line of standard output is one JSON object: correct,

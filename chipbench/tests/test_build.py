@@ -1,5 +1,6 @@
 """The served weights: the leaf-by-leaf build gives what one quantize_tree
-call gives, and the reference derives the same weights on its own."""
+call gives, and the dense decoder's reference derives the same weights
+on its own."""
 import json
 
 import jax
@@ -8,9 +9,11 @@ import numpy as np
 import pytest
 from conftest import BENCH_DIR
 
-from harness import build, reference
+from harness import arch, build
+from harness.refquant import seed_key
 
 SEED = 2 ** 31 + 77
+reference = arch.load(arch.DEFAULT)
 
 
 @pytest.fixture(scope="module", params=["rms", "layer"])
@@ -30,7 +33,7 @@ def test_leaf_by_leaf_equals_one_call(tiny):
     from repro.models import get_model
 
     cfg = build.arch_config(tiny)
-    key = reference.seed_key(SEED)
+    key = seed_key(SEED)
     params = jax.jit(get_model(cfg).init, static_argnums=1)(key, cfg)
     q = tiny["quant"]
     one, _ = quantize_tree(key, params, QuantPolicy(
